@@ -150,10 +150,10 @@ def bench_backend_channels(smoke: bool = False):
     """Backend x channel-count comparison behind one simulate() surface.
 
     Times the jnp reference against the Pallas arbiter kernel and the
-    fused full-cycle kernel on 1-channel (wide-only), 3-channel (paper
+    fused router-update kernel on 1-channel (wide-only), 3-channel (paper
     narrow-wide) and 4-channel (2-stream) specs, checks them
     flit-for-flit equivalent, and records everything into
-    BENCH_noc.json.  Off-TPU the Pallas backends run interpreted, so
+    BENCH_noc.json.  On the CPU the Pallas backends run interpreted, so
     their timings measure correctness cost, not kernel speed."""
     from repro.noc import NocSpec, Workload, simulate
     cycles = 1000 if smoke else 3000
@@ -209,7 +209,7 @@ def bench_write_mix(smoke: bool = False):
     For each mix, every backend must agree flit-for-flit (asserted);
     the derived metrics record per-direction completions/latency and
     the per-channel link-move shift as W bursts move to the wide
-    channel and B acks load the rsp channel.  Off-TPU the Pallas
+    channel and B acks load the rsp channel.  On the CPU the Pallas
     backends run interpreted (correctness cost, not kernel speed)."""
     from repro.noc import NocSpec, Workload, simulate
     cycles = 1500 if smoke else 4000
@@ -500,7 +500,7 @@ def bench_engine_throughput(smoke: bool = False):
             cycles=cycles)
 
     # backend x mesh x channel-count steps/sec grid (interpret-mode
-    # Pallas off-TPU: correctness cost, not kernel speed)
+    # Pallas on the CPU: correctness cost, not kernel speed)
     grid_cycles = 300 if smoke else 1000
     grid = [("jnp", 4, NocSpec.narrow_wide, "3ch"),
             ("jnp", 8, NocSpec.narrow_wide, "3ch"),
@@ -557,19 +557,16 @@ def _sweep_scaling_points(smoke: bool):
     return pts
 
 
-def _sweep_scaling_worker(devices: int, smoke: bool) -> None:
-    """Child-process body for one device count: XLA_FLAGS (set by the
-    parent BEFORE this process imported jax) provides the fake host
-    devices; prints one JSON line the parent parses."""
-    import hashlib
-
+def _sweep_scaling_run(devices: int, smoke: bool) -> dict:
+    """Run the campaign twice on ``jax.devices()[:devices]`` (the first
+    call compiles) and return its stats and result digest."""
     import jax
     from repro.noc import sim_cache_clear, sim_cache_stats, sweep
 
     if jax.device_count() < devices:
         raise SystemExit(
-            f"worker wanted {devices} devices, jax sees "
-            f"{jax.device_count()} — XLA_FLAGS not applied before import?")
+            f"wanted {devices} devices, jax sees {jax.device_count()} — "
+            f"XLA_FLAGS not applied before import?")
     pts = _sweep_scaling_points(smoke)
     sim_cache_clear()
     t0 = time.perf_counter()
@@ -583,9 +580,19 @@ def _sweep_scaling_worker(devices: int, smoke: bool) -> None:
     # shard_map wrapper serve the whole campaign, and the second call
     # reuses both — the farm partition must not recompile per call
     assert misses == 2, f"farm sweep built {misses} fns, expected 2"
+    return {"devices": devices, "n_specs": len(pts),
+            "specs_per_sec": len(pts) / run_s,
+            "run_s": round(run_s, 4), "compile_s": round(compile_s, 2),
+            "compiles": misses, "digest": sweep_digest(out)}
+
+
+def sweep_digest(results) -> str:
+    """sha256 over every point's per-class stats and link moves — equal
+    digests mean bit-identical sweeps."""
+    import hashlib
 
     h = hashlib.sha256()
-    for m in out:
+    for m in results:
         for cname in sorted(m.classes):
             c = m.classes[cname]
             for f in ("done", "avg_lat", "max_lat", "beats_rx", "w_done",
@@ -594,29 +601,37 @@ def _sweep_scaling_worker(devices: int, smoke: bool) -> None:
         for ch in sorted(m.channels):
             h.update(np.ascontiguousarray(
                 m.channels[ch].link_moves).tobytes())
-    print(json.dumps({
-        "devices": devices, "n_specs": len(pts),
-        "specs_per_sec": len(pts) / run_s,
-        "run_s": round(run_s, 4), "compile_s": round(compile_s, 2),
-        "compiles": misses, "digest": h.hexdigest()}))
+    return h.hexdigest()
 
 
 def bench_sweep_scaling(smoke: bool = False):
-    """Tentpole bench: the device-parallel sweep farm at 1/2/4/8 (host)
-    devices over the same >=64-spec campaign, each count in its own
-    subprocess so ``XLA_FLAGS=--xla_force_host_platform_device_count``
-    lands before jax import.
+    """Tentpole bench: the device-parallel sweep farm at 1/2/4/8
+    devices over the same >=64-spec campaign.
+
+    On an accelerator every count runs in this process over
+    ``jax.devices()[:n]``, for each count up to the visible devices (a
+    chip belongs to one process, so a child could not reach it).  On
+    the CPU each count runs in its own subprocess so
+    ``XLA_FLAGS=--xla_force_host_platform_device_count`` lands before
+    jax import.
 
     Records specs/sec and parallel efficiency per device count plus the
     result digest — asserted identical across counts (sharding must be
     bit-invisible).  Host 'devices' share this machine's physical
-    cores, so real speedup needs real cores: the >=5x floor at 8
+    cores, so real speedup needs real cores: the >=5x floor at 8 host
     devices is asserted only when the host has >= 8 cores, and the
     honest per-count numbers + core count are recorded either way."""
-    devices_list = (1, 2, 4, 8)
+    import jax
+
+    on_cpu = jax.default_backend() == "cpu"
+    devices_list = (1, 2, 4, 8) if on_cpu else tuple(
+        n for n in (1, 2, 4, 8) if n <= jax.device_count())
     cores = os.cpu_count() or 1
     stats = {}
     for n in devices_list:
+        if not on_cpu:
+            stats[n] = _sweep_scaling_run(n, smoke)
+            continue
         env = dict(os.environ)
         flags = env.get("XLA_FLAGS", "")
         flags = " ".join(f for f in flags.split()
@@ -655,12 +670,12 @@ def bench_sweep_scaling(smoke: bool = False):
                 efficiency=eff, n_specs=s["n_specs"],
                 compiles=s["compiles"], cores=cores,
                 bit_identical=True)
-    if cores >= 8:
+    if on_cpu and cores >= 8:
         assert stats[8]["specs_per_sec"] >= 5 * sps1, (
             f"sweep(devices=8) reached only "
             f"{stats[8]['specs_per_sec'] / sps1:.2f}x over devices=1 "
             f"on a {cores}-core host (need >= 5x)")
-    else:
+    elif on_cpu:
         print(f"# sweep_scaling: {cores} core(s) < 8 — host devices "
               f"share cores, >=5x floor not asserted (numbers above "
               f"are the honest single-core serialization)")
@@ -871,8 +886,12 @@ def main() -> None:
     ap.add_argument("--sweep-worker", type=int, default=None,
                     metavar="N", help=argparse.SUPPRESS)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.sweep_worker is not None:
-        _sweep_scaling_worker(args.sweep_worker, args.smoke)
+        # child of bench_sweep_scaling's CPU path: one JSON line
+        print(json.dumps(_sweep_scaling_run(args.sweep_worker,
+                                            args.smoke)))
         return
     if args.tpu:
         import jax

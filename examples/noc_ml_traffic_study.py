@@ -18,6 +18,7 @@ os.environ.setdefault("XLA_FLAGS",
 import jax                                                   # noqa: E402
 import numpy as np                                           # noqa: E402
 
+from repro.compile_cache import enable_compile_cache        # noqa: E402
 from repro.configs import ShapeConfig, get_arch              # noqa: E402
 from repro.configs.base import MeshConfig, RunConfig         # noqa: E402
 from repro.core.channels import Ledger                       # noqa: E402
@@ -25,6 +26,7 @@ from repro.dist import step as step_lib                      # noqa: E402
 from repro.models import build_model                         # noqa: E402
 from repro.noc import NocSpec, Workload, simulate            # noqa: E402
 
+enable_compile_cache()
 MESH_CFG = MeshConfig(data=2, model=2, pod=1)
 
 

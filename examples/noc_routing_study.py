@@ -22,8 +22,11 @@ story on the cycle-level simulator:
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.noc import (Mesh, NocSpec, RoutingPolicy, Torus, Workload,
                        simulate)
+
+enable_compile_cache()
 
 CYCLES = 3500
 wl = Workload.make("all_to_all", rates={"wide": 1.0}, rounds={"wide": 4},

@@ -11,7 +11,10 @@ Table-I/Fig-6 quantities.
 import numpy as np
 
 from repro.core.noc_sim import PAPER, PAPER_CLAIMS
+from repro.compile_cache import enable_compile_cache
 from repro.noc import NocSpec, Workload, simulate, simulate_batch
+
+enable_compile_cache()
 
 print("=== Table I / bandwidth (analytic) ===")
 print(f"wide link: {PAPER.wide_link_gbps():.0f} Gbps "

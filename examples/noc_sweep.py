@@ -16,8 +16,11 @@ the paper's two fixed configurations:
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.noc import (Mesh, NocSpec, Torus, Workload, hop_table, simulate,
                        simulate_batch)
+
+enable_compile_cache()
 
 # ------------------------------------------------------------------ #
 # 1. one-jit rate sweep

@@ -13,7 +13,10 @@ wormhole bursts can wedge (see ROADMAP).
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.noc import NocSpec, Torus, Workload, simulate, simulate_batch
+
+enable_compile_cache()
 
 print("=== read/write mix sweep (one vmapped jit) ===")
 spec = NocSpec.narrow_wide(4, 4, cycles=6000)

@@ -5,10 +5,13 @@ Runs on a single CPU device in ~a minute:
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch, ShapeConfig
 from repro.configs.base import MeshConfig, RunConfig
 from repro.serve import Engine
 from repro.train.loop import train
+
+enable_compile_cache()
 
 mcfg = get_arch("llama3.2-1b").smoke()           # reduced same-family config
 shape = ShapeConfig("quickstart", seq_len=64, global_batch=8, kind="train")

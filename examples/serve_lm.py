@@ -6,9 +6,12 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch, ShapeConfig
 from repro.configs.base import MeshConfig, RunConfig
 from repro.serve import Engine
+
+enable_compile_cache()
 
 mcfg = get_arch("llama3.2-1b").smoke(num_layers=4, d_model=256, d_ff=1024,
                                      vocab_size=8192, name="serve-demo")

@@ -10,12 +10,14 @@ import dataclasses
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch, ShapeConfig
 from repro.configs.base import MeshConfig, RunConfig
 from repro.train.loop import train
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--small", action="store_true")

@@ -1,5 +1,3 @@
 """FlooNoC-JAX: a multi-pod JAX training/serving framework built on
 FlooNoC's narrow-wide, endpoint-ordered, dimension-routed NoC principles."""
-from . import _jax_compat  # noqa: F401  (backfills renamed JAX entry points)
-
 __version__ = "0.1.0"

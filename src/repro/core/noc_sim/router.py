@@ -40,14 +40,17 @@ Flit fields: [dest_router, src_router, inject_time, kind, txn_id, beat].
 :func:`repro.core.flit.flow_kind` — the fabric never decodes it (flits
 of AR/R reads and AW/W/B writes route identically); only the NI model
 in ``repro.noc.engine`` interprets kinds.
-The per-cycle update (`make_fabric_step`) is the hot loop; its phase-B
-arbitration is pluggable (``arbiter=``) so the Pallas kernel in
-``kernels/noc_router.py`` can replace the jnp reference
-(:func:`arbiter_jnp`) behind the same engine — see
-``repro.noc.backends``.
+The per-cycle update (`make_fabric_step`) is the hot loop.  It is two
+halves: :func:`fabric_front`, everything read *across* router rows
+(drain, neighbor push, NI injection, route lookup — all from the
+cycle-start state), and :func:`fabric_update`, the row-local rest
+(arbitration, output-register and FIFO update).  Every backend and the
+row-sharded farm share the front; the update's arbitration is pluggable
+(``arbiter=``), and the fused Pallas kernel in ``kernels/noc_router.py``
+replaces the whole update — see ``repro.noc.backends``.
 
-Two hot-path properties this module guarantees (the fused Pallas kernel
-and the padded-depth sweep mode both rely on them):
+Two hot-path properties this module guarantees (the backends and the
+padded-depth sweep mode rely on them):
 
 * the neighbor push is expressed as a static *gather* through the
   precomputed inverse link map (:func:`feeder_tables`) — every input
@@ -166,12 +169,159 @@ def feeder_tables(nbr: np.ndarray,
     return src_r, src_o
 
 
+class FabricTables(NamedTuple):
+    """A fabric's static tables as device constants, in the form the
+    cycle reads them: ``nbr``/``opp``/``route`` as in
+    ``repro.noc.topology``, plus the inverse link map flattened to one
+    ``src_flat[r, p] = feeder_row * P + feeder_port`` index with its
+    ``has_feed`` mask (:func:`feeder_tables`)."""
+    nbr: jax.Array       # (R, P) int32
+    opp: jax.Array       # (R, P) int32
+    route: jax.Array     # (R, n_dest) int32
+    has_feed: jax.Array  # (R, P) bool
+    src_flat: jax.Array  # (R, P) int32
+
+
+def fabric_tables(nbr: np.ndarray, opp: np.ndarray,
+                  route: np.ndarray) -> FabricTables:
+    src_r, src_o = feeder_tables(nbr, opp)
+    P = nbr.shape[1]
+    return FabricTables(
+        nbr=jnp.asarray(nbr, jnp.int32), opp=jnp.asarray(opp, jnp.int32),
+        route=jnp.asarray(route, jnp.int32),
+        has_feed=jnp.asarray(src_r >= 0),
+        src_flat=jnp.asarray(np.clip(src_r, 0, None) * P
+                             + np.clip(src_o, 0, None), jnp.int32))
+
+
+class Front(NamedTuple):
+    """Everything one cycle reads *across* router rows — all of it a
+    function of the cycle-start state (the fabric's registers are
+    registered, so nothing a router decides this cycle is seen by its
+    neighbours before the next)."""
+    drain: jax.Array          # (R, P) bool  output registers that move
+    recv_valid: jax.Array     # (R, P) bool  input ports receiving a flit
+    recv_flit: jax.Array      # (R, P, F)    ... and that flit
+    out_port: jax.Array       # (R, P) int32 routed output per head
+    inj_ok: jax.Array         # (R,) bool    NI injection accepted
+    deliver_valid: jax.Array  # (R,) bool    Local output drained to the NI
+    deliver_flit: jax.Array   # (R, F)
+    link_moves: jax.Array     # ()  int32    non-local flits moved
+
+
+def serialize_drain(ready: jax.Array, n_vcs: int) -> jax.Array:
+    """At most one drained VC per physical link: highest ready VC index
+    wins (escape-VC priority).  Identity when ``n_vcs == 1``."""
+    if n_vcs == 1:
+        return ready
+    R, P = ready.shape
+    e = ready[:, :P - 1].reshape(R, (P - 1) // n_vcs, n_vcs)
+    rank = jnp.where(e, jnp.arange(n_vcs)[None, None, :], -1)
+    win = e & (rank == jnp.max(rank, axis=2, keepdims=True))
+    return jnp.concatenate([win.reshape(R, P - 1), ready[:, P - 1:]],
+                           axis=1)
+
+
+def fabric_front(state: NetState, inject_valid: jax.Array,
+                 inject_flit: jax.Array, depth: jax.Array,
+                 tables: FabricTables, *, n_vcs: int = 1,
+                 link_mask: jax.Array | None = None,
+                 ext=None) -> Front:
+    """Phase A (output-register drain), the neighbor push, NI injection
+    and the route lookup — the cross-row half of one cycle.
+
+    ``ext`` maps a per-row array onto the row space the tables index:
+    the identity for a whole fabric, the halo-extended strip for a
+    row-sharded one (``repro.noc.farm``).  ``link_mask (R, P) bool``
+    (fault injection) marks output ports whose link is dead this cycle:
+    they never drain, so flits wait under ordinary backpressure."""
+    if ext is None:
+        def ext(x):
+            return x
+    R, P = state.count.shape
+    L = P - 1
+    is_local = jnp.arange(P)[None, :] == L
+    # downstream input-FIFO occupancy (registered, cycle start)
+    count_x = ext(state.count)
+    ds_count = count_x[jnp.clip(tables.nbr, 0, count_x.shape[0] - 1),
+                       tables.opp]
+    can_drain = jnp.where(is_local, True,        # Local: NI always sinks
+                          (tables.nbr >= 0) & (ds_count < depth))
+    if link_mask is not None:
+        can_drain &= ~link_mask
+    drain = serialize_drain(state.oreg_v & can_drain, n_vcs)
+
+    # pushes into neighbor input FIFOs, as ONE static gather through
+    # the inverse link map (each input port has at most one feeder,
+    # so this is exactly the seed's per-output-port scatter loop)
+    recv_valid = tables.has_feed & ext(drain).reshape(-1)[tables.src_flat]
+    recv_flit = jnp.where(
+        recv_valid[:, :, None],
+        ext(state.oreg).reshape(-1, N_FIELDS)[tables.src_flat], 0)
+
+    # NI injection into the Local input port (cycle-start occupancy)
+    inj_ok = inject_valid & (state.count[:, L] < depth)
+    recv_valid = recv_valid.at[:, L].set(inj_ok)
+    recv_flit = recv_flit.at[:, L].set(
+        jnp.where(inj_ok[:, None], inject_flit, 0))
+
+    heads = state.fifo[:, :, 0, :]                               # (R, P, F)
+    out_port = tables.route[jnp.arange(R)[:, None], heads[:, :, F_DEST]]
+    out_port = jnp.where(state.count > 0, out_port, NO_PORT)
+    link_moves = jnp.sum(jnp.where(is_local, 0, drain.astype(jnp.int32)))
+    return Front(drain=drain, recv_valid=recv_valid, recv_flit=recv_flit,
+                 out_port=out_port, inj_ok=inj_ok,
+                 deliver_valid=drain[:, L], deliver_flit=state.oreg[:, L, :],
+                 link_moves=link_moves)
+
+
+def fabric_update(state: NetState, front: Front, depth: jax.Array,
+                  arbiter=None) -> NetState:
+    """Phase B (arbitration into freed output registers) and the input
+    FIFO pop/push — the row-local half of one cycle, which the fused
+    Pallas kernel replaces (``kernels/noc_router.py``).
+
+    Wormhole: a multi-flit packet (burst) locks its output port from
+    the first beat until the tail beat (F_BEAT <= 1) has passed, so
+    burst beats are never interleaved — the paper's burst semantics."""
+    arb = arbiter_jnp if arbiter is None else arbiter
+    R = state.count.shape[0]
+    r_idx = jnp.arange(R)[:, None]
+    heads = state.fifo[:, :, 0, :]                               # (R, P, F)
+    oreg_free = (~state.oreg_v) | front.drain
+    winner, pop, new_ptr, new_lock = arb(
+        front.out_port, heads[:, :, F_BEAT], state.rr_ptr, oreg_free,
+        state.lock_in)
+
+    any_grant = winner >= 0
+    flit_to_oreg = heads[r_idx, jnp.clip(winner, 0)]             # (R, P, F)
+    new_oreg_v = (state.oreg_v & ~front.drain) | any_grant
+    new_oreg = jnp.where(any_grant[:, :, None], flit_to_oreg, state.oreg)
+
+    D = state.fifo.shape[2]                              # static max depth
+    shifted = jnp.concatenate(
+        [state.fifo[:, :, 1:, :], jnp.zeros_like(state.fifo[:, :, :1, :])],
+        axis=2)
+    fifo = jnp.where(pop[:, :, None, None], shifted, state.fifo)
+    count = state.count - pop.astype(jnp.int32)
+
+    slot = jnp.clip(count, 0, D - 1)
+    write = front.recv_valid & (count < depth)
+    onehot_slot = jax.nn.one_hot(slot, D, dtype=jnp.bool_)       # (R, P, D)
+    sel = write[:, :, None] & onehot_slot
+    fifo = jnp.where(sel[..., None], front.recv_flit[:, :, None, :], fifo)
+    count = count + write.astype(jnp.int32)
+    return NetState(fifo=fifo, count=count, rr_ptr=new_ptr, oreg=new_oreg,
+                    oreg_v=new_oreg_v, lock_in=new_lock)
+
+
 def make_fabric_step(nbr: np.ndarray, opp: np.ndarray, route: np.ndarray,
                      arbiter=None, n_vcs: int = 1, masked: bool = False):
     """Build the one-cycle update for a fabric described by static
     tables (see ``repro.noc.topology``): ``nbr[r, p]`` neighbor router
     per output port (-1 none, local port last), ``opp[r, p]`` the input
-    port the link feeds, ``route[r, d]`` the routed output port.
+    port the link feeds, ``route[r, d]`` the routed output port.  The
+    step is :func:`fabric_front` then :func:`fabric_update`.
 
     ``arbiter`` replaces the phase-B arbitration (same signature and
     semantics as :func:`arbiter_jnp`) — the hook the Pallas backend
@@ -200,103 +350,18 @@ def make_fabric_step(nbr: np.ndarray, opp: np.ndarray, route: np.ndarray,
     transparently when the mask clears.  The default build does not
     trace the mask at all, keeping the healthy path bit-identical.
     """
-    R, P = nbr.shape
-    PORT_L = P - 1
-    nbr_j = jnp.asarray(nbr, jnp.int32)
-    opp_j = jnp.asarray(opp, jnp.int32)
-    route_j = jnp.asarray(route, jnp.int32)
-    src_r, src_o = feeder_tables(nbr, opp)
-    has_feed = jnp.asarray(src_r >= 0)                            # (R, P)
-    src_flat = jnp.asarray(np.clip(src_r, 0, None) * P
-                           + np.clip(src_o, 0, None), jnp.int32)  # (R, P)
-    arb = arbiter_jnp if arbiter is None else arbiter
-    r_idx = jnp.arange(R)
-    if (P - 1) % n_vcs:
+    if (nbr.shape[1] - 1) % n_vcs:
         raise ValueError(
-            f"{P - 1} non-local ports do not fold into {n_vcs} VCs")
-    n_phys = (P - 1) // n_vcs
-
-    def serialize_drain(ready):
-        """At most one drained VC per physical link: highest ready VC
-        index wins (escape-VC priority).  Identity when n_vcs == 1."""
-        if n_vcs == 1:
-            return ready
-        e = ready[:, :P - 1].reshape(R, n_phys, n_vcs)
-        rank = jnp.where(e, jnp.arange(n_vcs)[None, None, :], -1)
-        win = e & (rank == jnp.max(rank, axis=2, keepdims=True))
-        return jnp.concatenate(
-            [win.reshape(R, P - 1), ready[:, P - 1:]], axis=1)
+            f"{nbr.shape[1] - 1} non-local ports do not fold into "
+            f"{n_vcs} VCs")
+    tables = fabric_tables(nbr, opp, route)
 
     def step(state: NetState, inject_valid: jax.Array,
              inject_flit: jax.Array, depth: jax.Array, *fault_args):
-        heads = state.fifo[:, :, 0, :]                    # (R, P, F)
-        head_valid = state.count > 0                      # (R, P)
-
-        # ---------------- phase A: drain output registers -------------------
-        # downstream input-FIFO occupancy (registered, cycle start)
-        ds_count = state.count[jnp.clip(nbr_j, 0, R - 1), opp_j]   # (R, P)
-        can_drain = jnp.where(jnp.arange(P)[None, :] == PORT_L,
-                              True,                     # Local: NI always sinks
-                              (nbr_j >= 0) & (ds_count < depth))
-        if masked:
-            (link_mask,) = fault_args                   # (R, P) bool, traced
-            can_drain &= ~link_mask
-        drain = serialize_drain(state.oreg_v & can_drain)
-
-        deliver_valid = drain[:, PORT_L]
-        deliver_flit = state.oreg[:, PORT_L, :]
-
-        # pushes into neighbor input FIFOs, as ONE static gather through
-        # the inverse link map (each input port has at most one feeder,
-        # so this is exactly the seed's per-output-port scatter loop)
-        recv_valid = has_feed & drain.reshape(-1)[src_flat]        # (R, P)
-        recv_flit = jnp.where(
-            recv_valid[:, :, None],
-            state.oreg.reshape(-1, N_FIELDS)[src_flat], 0)         # (R, P, F)
-
-        # NI injection into Local input port (cycle-start occupancy)
-        local_ready = state.count[:, PORT_L] < depth
-        inj_ok = inject_valid & local_ready
-        recv_valid = recv_valid.at[:, PORT_L].set(inj_ok)
-        recv_flit = recv_flit.at[:, PORT_L].set(
-            jnp.where(inj_ok[:, None], inject_flit, 0))
-
-        # ---------------- phase B: arbitration into freed oregs -------------
-        # Wormhole: a multi-flit packet (burst) locks its output port from
-        # the first beat until the tail beat (F_BEAT <= 1) has passed, so
-        # burst beats are never interleaved — the paper's burst semantics.
-        oreg_free = (~state.oreg_v) | drain                        # (R, P)
-        out_port = route_j[r_idx[:, None], heads[:, :, F_DEST]]    # (R, P_in)
-        out_port = jnp.where(head_valid, out_port, NO_PORT)
-        winner, pop, new_ptr, new_lock = arb(
-            out_port, heads[:, :, F_BEAT], state.rr_ptr, oreg_free,
-            state.lock_in)
-
-        any_grant = winner >= 0
-        flit_to_oreg = heads[r_idx[:, None], jnp.clip(winner, 0)]  # (R, P, F)
-        new_oreg_v = (state.oreg_v & ~drain) | any_grant
-        new_oreg = jnp.where(any_grant[:, :, None], flit_to_oreg, state.oreg)
-
-        # ---------------- input FIFO update: pop then push ------------------
-        D = state.fifo.shape[2]                          # static max depth
-        shifted = jnp.concatenate(
-            [state.fifo[:, :, 1:, :],
-             jnp.zeros_like(state.fifo[:, :, :1, :])], axis=2)
-        fifo = jnp.where(pop[:, :, None, None], shifted, state.fifo)
-        count = state.count - pop.astype(jnp.int32)
-
-        slot = jnp.clip(count, 0, D - 1)
-        write = recv_valid & (count < depth)
-        onehot_slot = jax.nn.one_hot(slot, D, dtype=jnp.bool_)     # (R,P,D)
-        sel = write[:, :, None] & onehot_slot
-        fifo = jnp.where(sel[..., None], recv_flit[:, :, None, :], fifo)
-        count = count + write.astype(jnp.int32)
-
-        new_state = NetState(fifo=fifo, count=count, rr_ptr=new_ptr,
-                             oreg=new_oreg, oreg_v=new_oreg_v,
-                             lock_in=new_lock)
-        link_moves = jnp.sum(drain.astype(jnp.int32)
-                             * (jnp.arange(P)[None, :] != PORT_L))
-        return new_state, inj_ok, deliver_valid, deliver_flit, link_moves
+        link_mask = fault_args[0] if masked else None
+        front = fabric_front(state, inject_valid, inject_flit, depth,
+                             tables, n_vcs=n_vcs, link_mask=link_mask)
+        return (fabric_update(state, front, depth, arbiter), front.inj_ok,
+                front.deliver_valid, front.deliver_flit, front.link_moves)
 
     return step

@@ -6,27 +6,26 @@ reference engine (``repro.core.noc_sim.router``):
 * :func:`router_arbiter_pallas` — phase-B only: round-robin arbitration
   of routed input heads into free output registers with wormhole burst
   locking, for a TILE of routers held in VMEM (``backend="pallas"``).
-* :func:`fused_fabric_step_pallas` — the FULL one-cycle network update
-  (paper's single-cycle router datapath): output-register drain,
-  neighbor push through the static inverse link map, arbitration, and
-  input-FIFO pop/push, in ONE kernel over an ``(N, P*D*F)``-flattened
-  row layout (``backend="pallas_fused"``).  ``N`` is routers with every
-  physical channel folded into extra rows, so one kernel launch per
-  simulated cycle advances the entire fabric — all channels, all
-  routers — and the last axis stays a long contiguous lane dimension.
+* :func:`fused_fabric_step_pallas` — the row-local half of the one-cycle
+  network update (``backend="pallas_fused"``): arbitration, the
+  output-register update and the input-FIFO pop/push, in ONE kernel
+  over an ``(N, P*D*F)``-flattened row layout.  ``N`` is routers with
+  every physical channel folded into extra rows, so one kernel launch
+  per simulated cycle updates every router of every channel.  The
+  cross-row half — output-register drain, the neighbor push through
+  the inverse link map, NI injection and the route-table lookup — is
+  :func:`repro.core.noc_sim.router.fabric_front` in jnp, from the
+  cycle-start state, and reaches the kernel as ``(N, P)`` operands.
 
-Route compute is a static-table gather (``route[row, dest]``), so the
-same kernels serve the XY mesh, the torus, and >5-port express-link
-routers: the port count is a static parameter.  FIFO depth reaches the
-fused kernel as a traced per-row operand masked against the static
-``D`` max, matching the engine's padded-depth sweep mode.
+Both kernels compile for TPU v5e through Mosaic
+(``tests/test_tpu_compile.py`` compiles them for a described v5e at the
+paper's mesh sizes).  Mosaic accepts 2-D arrays, static slices, integer
+min/sum/any reductions and elementwise math here, so the kernels use
+nothing else: no in-kernel gather, no integer argmax, no 3-D reshape.
+Interpret mode is for the CPU only (``interpret=None`` picks it there);
+on a TPU the kernels always compile.
 
-Off-TPU both kernels auto-select interpret mode; the row layout is
-(8, 128)-tileable for a real Mosaic lowering, but the in-kernel static
-gathers have only been validated under the interpreter (see README
-"Performance" and ROADMAP).
-
-Layout (R routers, P ports, blocked over R):
+Arbiter layout (R routers, P ports, blocked over R):
   out_port  (R, P) int32   routed output port per input head (99: empty)
   beat      (R, P) int32   remaining burst beats per input head
   rr_ptr    (R, P) int32   per-output round-robin pointer
@@ -35,15 +34,13 @@ Layout (R routers, P ports, blocked over R):
 outputs:
   winner    (R, P) int32   granted input per output (-1: none)
   pop       (R, P) int32   input head consumed
-  new_ptr   (R, P) int32   (advances only on unlocked grants — matching
-                           the engine; the seed kernel advanced it on
-                           locked grants too, breaking parity)
+  new_ptr   (R, P) int32   (advances only on unlocked grants, like the
+                           engine)
   new_lock  (R, P) int32
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -51,43 +48,57 @@ from jax.experimental import pallas as pl
 
 NO = 99
 
-# conservative per-core VMEM budget for the no-grid fused kernel: every
-# operand and output lives in VMEM at once, so a real Mosaic lowering of
-# an oversized fabric dies with an opaque allocator error deep inside
-# the compiler.  16 MiB matches the usable fraction of a v4/v5 core's
-# VMEM after double-buffering headroom.
+# VMEM budget for the no-grid fused kernel: every operand and output
+# lives in VMEM at once, so a real Mosaic lowering of an oversized
+# fabric dies with an opaque allocator error deep inside the compiler.
+# 16 MiB is Mosaic's default scoped-VMEM limit on v5e.
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 
-def _arbitrate(out_port, beat, ptr, free, lock, *, n_rows: int, n_ports: int):
-    """Shared phase-B math: ``free``/``lock`` per OUT port, ``out_port``/
-    ``beat`` per IN head.  Returns (winner, pop, new_ptr, new_lock)."""
+def _interpret_default() -> bool:
+    """Interpret mode is for the CPU only: on an accelerator the kernels
+    compile (Mosaic), and a platform they cannot compile for fails
+    loudly instead of silently interpreting."""
+    return jax.default_backend() == "cpu"
+
+
+def _arbitrate(out_port, beat, ptr, free, lock, *, n_ports: int):
+    """Shared phase-B math: ``free``/``lock``/``ptr`` per OUT port,
+    ``out_port``/``beat`` per IN head, all ``(rows, P)``.  Returns
+    ``(winner, pop, new_ptr, new_lock)``.
+
+    One static pass per output port over ``(rows, P)`` arrays (inputs
+    on the lane axis), so every intermediate stays 2-D — a ``(rows, P,
+    P)`` one would take a whole (8, 128) VMEM tile per row.  Mosaic has
+    no integer argmax: the winner is the least input id among the
+    requests holding the best score (scores are distinct, so that is
+    the one)."""
     P = n_ports
-    o_ids = jax.lax.broadcasted_iota(jnp.int32, (n_rows, P, P), 2)
-    i_ids = jax.lax.broadcasted_iota(jnp.int32, (n_rows, P, P), 1)
-    req = (out_port[:, :, None] == o_ids) & free[:, None, :]
-    locked = lock[:, None, :] >= 0
-    req &= (~locked) | (i_ids == lock[:, None, :])
-
-    prio = (i_ids - ptr[:, None, :]) % P
-    score = jnp.where(req, prio, NO)
-    best = jnp.min(score, axis=1)                     # (rows, P_out)
-    granted = best < NO
-    # winner = first input matching best score (scores are distinct)
-    is_best = (score == best[:, None, :]) & req
-    winner = jnp.argmax(is_best.astype(jnp.int32), axis=1)
-    winner = jnp.where(granted, winner, -1)
-
-    pop = jnp.any((i_ids == winner[:, None, :]) & granted[:, None, :], axis=2)
-    # rr pointer holds while an output is wormhole-locked
-    new_ptr = jnp.where(granted & (lock < 0), (winner + 1) % P, ptr)
-
-    # lock update from granted flit's beat field
-    w_beat = jnp.sum(jnp.where((i_ids == winner[:, None, :])
-                               & granted[:, None, :],
-                               beat[:, :, None], 0), axis=1)
-    new_lock = jnp.where(granted & (w_beat > 1), winner,
-                         jnp.where(granted, -1, lock))
+    rows = out_port.shape[0]
+    i_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, P), 1)
+    winner = jnp.full((rows, P), -1, jnp.int32)
+    pop = jnp.zeros((rows, P), jnp.bool_)
+    new_ptr, new_lock = ptr, lock
+    for o in range(P):
+        lock_o = lock[:, o:o + 1]
+        req = (out_port == o) & free[:, o:o + 1]
+        req &= (lock_o < 0) | (i_ids == lock_o)
+        score = jnp.where(req, (i_ids - ptr[:, o:o + 1]) % P, NO)
+        best = jnp.min(score, axis=1, keepdims=True)
+        granted = best < NO
+        w = jnp.min(jnp.where(req & (score == best), i_ids, P), axis=1,
+                    keepdims=True)
+        w = jnp.where(granted, w, -1)                        # (rows, 1)
+        hit = i_ids == w
+        pop |= hit
+        w_beat = jnp.sum(jnp.where(hit, beat, 0), axis=1, keepdims=True)
+        this = i_ids == o
+        winner = jnp.where(this, w, winner)
+        # rr pointer holds while an output is wormhole-locked
+        new_ptr = jnp.where(this & granted & (lock_o < 0), (w + 1) % P,
+                            new_ptr)
+        new_lock = jnp.where(this & granted,
+                             jnp.where(w_beat > 1, w, -1), new_lock)
     return winner, pop, new_ptr, new_lock
 
 
@@ -95,11 +106,10 @@ def _arbitrate(out_port, beat, ptr, free, lock, *, n_rows: int, n_ports: int):
 # phase-B arbiter kernel (backend="pallas")
 # --------------------------------------------------------------------- #
 def _arb_kernel(oport_ref, beat_ref, ptr_ref, free_ref, lock_ref,
-                win_ref, pop_ref, nptr_ref, nlock_ref, *, n_ports: int,
-                block_r: int):
+                win_ref, pop_ref, nptr_ref, nlock_ref, *, n_ports: int):
     winner, pop, new_ptr, new_lock = _arbitrate(
         oport_ref[...], beat_ref[...], ptr_ref[...], free_ref[...] > 0,
-        lock_ref[...], n_rows=block_r, n_ports=n_ports)
+        lock_ref[...], n_ports=n_ports)
     win_ref[...] = winner
     pop_ref[...] = pop.astype(jnp.int32)
     nptr_ref[...] = new_ptr
@@ -122,11 +132,11 @@ def router_arbiter_pallas(out_port, beat, rr_ptr, oreg_free, lock_in,
     :func:`repro.core.noc_sim.router.arbiter_jnp` (``oreg_free`` may be
     bool or int mask; ``pop`` comes back as int32 0/1).
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU.
+    ``interpret=None`` selects interpreter mode on the CPU only.
     """
     R, P = out_port.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_default()
     block_r, R_pad = _pad_rows(R, block_r)
     grid = (R_pad // block_r,)
 
@@ -137,7 +147,7 @@ def router_arbiter_pallas(out_port, beat, rr_ptr, oreg_free, lock_in,
         return jnp.concatenate(
             [a, jnp.full((R_pad - R, P), fill, jnp.int32)], axis=0)
 
-    kernel = functools.partial(_arb_kernel, n_ports=P, block_r=block_r)
+    kernel = functools.partial(_arb_kernel, n_ports=P)
     spec = pl.BlockSpec((block_r, P), lambda i: (i, 0))
     out = pl.pallas_call(
         kernel,
@@ -152,133 +162,81 @@ def router_arbiter_pallas(out_port, beat, rr_ptr, oreg_free, lock_in,
 
 
 # --------------------------------------------------------------------- #
-# fused full-cycle fabric kernel (backend="pallas_fused")
+# fused router-update kernel (backend="pallas_fused")
 # --------------------------------------------------------------------- #
 def _fused_kernel(fifo_ref, count_ref, ptr_ref, oreg_ref, oregv_ref,
-                  lock_ref, iv_ref, iflit_ref, depth_ref, *rest,
-                  n_rows: int, n_ports: int, d_max: int, n_fields: int,
-                  f_dest: int, f_beat: int, n_vcs: int, masked: bool):
-    # fault injection (masked=True) inserts one extra (N, P) link-mask
-    # operand after depth; the healthy build keeps the original operand
-    # list so the zero-fault program is untouched
-    if masked:
-        (mask_ref, nbr_ref, opp_ref, route_ref, src_ref,
-         nfifo_ref, ncount_ref, nptr_ref, noreg_ref, noregv_ref,
-         nlock_ref, injok_ref, dv_ref, dflit_ref, lm_ref) = rest
-    else:
-        mask_ref = None
-        (nbr_ref, opp_ref, route_ref, src_ref,
-         nfifo_ref, ncount_ref, nptr_ref, noreg_ref, noregv_ref,
-         nlock_ref, injok_ref, dv_ref, dflit_ref, lm_ref) = rest
-    N, P, D, F = n_rows, n_ports, d_max, n_fields
-    fifo = fifo_ref[...].reshape(N, P, D, F)
-    count = count_ref[...]                                 # (N, P)
-    oreg = oreg_ref[...].reshape(N, P, F)
+                  lock_ref, drain_ref, oport_ref, rv_ref, rflit_ref,
+                  depth_ref, nfifo_ref, ncount_ref, nptr_ref, noreg_ref,
+                  noregv_ref, nlock_ref, *, n_ports: int, d_max: int,
+                  n_fields: int, f_beat: int):
+    P, D, F = n_ports, d_max, n_fields
+
+    def flit(ref, i):
+        """Flit ``i`` of a row's lane slab (a static lane slice)."""
+        return ref[:, i * F:(i + 1) * F]
+
+    drain = drain_ref[...] > 0
     oreg_v = oregv_ref[...] > 0
-    depth = depth_ref[...]                                 # (N, 1)
-    nbr = nbr_ref[...]
-    opp = opp_ref[...]
-    src = src_ref[...]
-
-    heads = fifo[:, :, 0, :]                               # (N, P, F)
-    head_valid = count > 0
-    is_local = (jax.lax.broadcasted_iota(jnp.int32, (N, P), 1) == P - 1)
-
-    # phase A: drain output registers toward downstream occupancy
-    ds_idx = jnp.clip(nbr, 0, N - 1) * P + opp             # (N, P)
-    ds_count = count.reshape(-1)[ds_idx]
-    can_drain = jnp.where(is_local, True, (nbr >= 0) & (ds_count < depth))
-    if masked:
-        can_drain &= mask_ref[...] == 0        # dead link: grants suppressed
-    drain = oreg_v & can_drain
-    if n_vcs > 1:
-        # VC-expanded tables: one physical link moves one flit/cycle, so
-        # keep only the highest ready VC (escape VC first) per link
-        n_phys = (P - 1) // n_vcs
-        e = drain[:, :P - 1].reshape(N, n_phys, n_vcs)
-        v_ids = jax.lax.broadcasted_iota(jnp.int32, (N, n_phys, n_vcs), 2)
-        rank = jnp.where(e, v_ids, -1)
-        win = e & (rank == jnp.max(rank, axis=2, keepdims=True))
-        drain = jnp.concatenate(
-            [win.reshape(N, P - 1), drain[:, P - 1:]], axis=1)
-
-    dv_ref[...] = drain[:, P - 1:].astype(jnp.int32)       # (N, 1)
-    dflit_ref[...] = oreg[:, P - 1, :]
-
-    # neighbor push == static gather through the inverse link map
-    recv_valid = (src >= 0) & drain.reshape(-1)[jnp.clip(src, 0)]
-    recv_flit = jnp.where(recv_valid[:, :, None],
-                          oreg.reshape(-1, F)[jnp.clip(src, 0)], 0)
-
-    # NI injection into the Local input port
-    inj_ok = (iv_ref[...][:, 0] > 0) & (count[:, P - 1] < depth[:, 0])
-    recv_valid = jnp.where(is_local, inj_ok[:, None], recv_valid)
-    recv_flit = jnp.where(is_local[:, :, None],
-                          jnp.where(inj_ok[:, None, None],
-                                    iflit_ref[...][:, None, :], 0),
-                          recv_flit)
-    injok_ref[...] = inj_ok[:, None].astype(jnp.int32)
+    # head flit of input port i is slot 0 of its D-slot FIFO
+    heads = [flit(fifo_ref, i * D) for i in range(P)]
+    beat = jnp.concatenate([h[:, f_beat:f_beat + 1] for h in heads], axis=1)
 
     # phase B: arbitration into freed output registers
-    oreg_free = (~oreg_v) | drain
-    out_port = jnp.take_along_axis(route_ref[...], heads[:, :, f_dest],
-                                   axis=1)
-    out_port = jnp.where(head_valid, out_port, NO)
     winner, pop, new_ptr, new_lock = _arbitrate(
-        out_port, heads[:, :, f_beat], ptr_ref[...], oreg_free,
-        lock_ref[...], n_rows=N, n_ports=P)
+        oport_ref[...], beat, ptr_ref[...], (~oreg_v) | drain,
+        lock_ref[...], n_ports=P)
     nptr_ref[...] = new_ptr
     nlock_ref[...] = new_lock
-
-    any_grant = winner >= 0
-    flit_to_oreg = jnp.take_along_axis(
-        heads, jnp.clip(winner, 0)[:, :, None], axis=1)
-    new_oreg = jnp.where(any_grant[:, :, None], flit_to_oreg, oreg)
-    noreg_ref[...] = new_oreg.reshape(N, P * F)
-    noregv_ref[...] = ((oreg_v & ~drain) | any_grant).astype(jnp.int32)
+    noregv_ref[...] = ((oreg_v & ~drain) | (winner >= 0)).astype(jnp.int32)
+    for o in range(P):
+        w = winner[:, o:o + 1]
+        out = flit(oreg_ref, o)
+        for i in range(P):
+            out = jnp.where(w == i, heads[i], out)
+        noreg_ref[:, o * F:(o + 1) * F] = out
 
     # input FIFO update: pop then push
-    shifted = jnp.concatenate(
-        [fifo[:, :, 1:, :], jnp.zeros_like(fifo[:, :, :1, :])], axis=2)
-    fifo = jnp.where(pop[:, :, None, None], shifted, fifo)
-    count = count - pop.astype(jnp.int32)
-
-    slot = jnp.clip(count, 0, D - 1)
-    write = recv_valid & (count < depth)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (N, P, D), 2)
-              == slot[:, :, None])
-    sel = write[:, :, None] & onehot
-    fifo = jnp.where(sel[..., None], recv_flit[:, :, None, :], fifo)
-    nfifo_ref[...] = fifo.reshape(N, P * D * F)
+    count = count_ref[...] - pop.astype(jnp.int32)
+    write = (rv_ref[...] > 0) & (count < depth_ref[...])
     ncount_ref[...] = count + write.astype(jnp.int32)
-
-    lm_ref[...] = jnp.sum((drain & ~is_local).astype(jnp.int32),
-                          axis=1, keepdims=True)
+    slot = jnp.clip(count, 0, D - 1)
+    for i in range(P):
+        pop_i, write_i = pop[:, i:i + 1], write[:, i:i + 1]
+        slot_i = slot[:, i:i + 1]
+        recv = flit(rflit_ref, i)
+        for d in range(D):
+            cur = flit(fifo_ref, i * D + d)
+            nxt = (flit(fifo_ref, i * D + d + 1) if d + 1 < D
+                   else jnp.zeros_like(cur))
+            cur = jnp.where(pop_i, nxt, cur)
+            cur = jnp.where(write_i & (slot_i == d), recv, cur)
+            nfifo_ref[:, (i * D + d) * F:(i * D + d + 1) * F] = cur
 
 
 def fused_fabric_step_pallas(fifo, count, rr_ptr, oreg, oreg_v, lock_in,
-                             inject_valid, inject_flit, depth_rows,
-                             nbr_rows, opp_rows, route_rows, src_rows,
-                             *, n_vcs: int = 1, link_mask_rows=None,
-                             interpret: bool | None = None,
+                             drain, out_port, recv_valid, recv_flit,
+                             depth_rows, *, interpret: bool | None = None,
                              vmem_budget_bytes: int | None =
                              VMEM_BUDGET_BYTES):
-    """One full fabric cycle for ``N`` stacked router rows (channels
-    folded into rows by the caller; see ``repro.noc.backends``).
+    """The row-local half of one fabric cycle for ``N`` stacked router
+    rows (channels folded into rows by the caller; see
+    ``repro.noc.backends``): arbitration into freed output registers,
+    the output-register update and the input-FIFO pop/push — the same
+    update as :func:`repro.core.noc_sim.router.fabric_update`.
+
+    The cross-row half arrives as operands, computed by
+    :func:`repro.core.noc_sim.router.fabric_front` from the cycle-start
+    state: ``drain (N, P)`` (output registers that move), ``out_port
+    (N, P)`` (routed output per input head, ``NO`` when empty) and the
+    neighbor push / NI injection ``recv_valid (N, P)``, ``recv_flit
+    (N, P, F)``.  So the kernel reads nothing outside its own row and
+    holds no route table.
 
     State arrives in the engine's logical shapes — ``fifo (N, P, D, F)``,
     ``oreg (N, P, F)``, the rest ``(N, P)`` — and is flattened to the
-    kernel's 2D ``(N, P*D*F)`` lane layout here.  The static tables are
-    row-indexed: ``nbr_rows``/``src_rows`` hold *row* (not router)
-    indices, ``route_rows`` is ``(N, n_planes*R)`` over per-network (possibly
-    multi-plane virtual) destinations.  ``depth_rows (N,)`` is the
-    traced per-row FIFO depth.  Static ``n_vcs > 1`` declares the port
-    axis VC-expanded and enables the per-physical-link drain
-    serialization (escape VC first), matching the jnp engine.
-    ``link_mask_rows (N, P)`` (fault injection) marks output ports whose
-    link is currently dead — they never drain; ``None`` (the default)
-    builds the original mask-free kernel, keeping the healthy program
-    untouched.
+    kernel's 2D ``(N, P*D*F)`` / ``(N, P*F)`` lane layout here; heads
+    and FIFO slots are static lane slices of it.  ``depth_rows (N,)``
+    is the traced per-row FIFO depth (<= the static ``D``).
 
     When compiling for a real TPU (``interpret=False``) the kernel is
     no-grid — every operand and output is resident in VMEM at once — so
@@ -287,20 +245,16 @@ def fused_fabric_step_pallas(fifo, count, rr_ptr, oreg, oreg_v, lock_in,
     the byte estimate and resharding hints instead of an opaque Mosaic
     allocator failure.  ``vmem_budget_bytes=None`` disables the check.
 
-    Returns ``(fifo, count, rr_ptr, oreg, oreg_v (int32), lock_in,
-    inj_ok (N,) bool, deliver_valid (N,) bool, deliver_flit (N, F),
-    link_moves_per_row (N,))``.
+    Returns ``(fifo, count, rr_ptr, oreg, oreg_v (int32), lock_in)``.
     """
-    from repro.core.noc_sim.router import F_BEAT, F_DEST
+    from repro.core.noc_sim.router import F_BEAT
 
     N, P, D, F = fifo.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_default()
 
-    masked = link_mask_rows is not None
-    kernel = functools.partial(
-        _fused_kernel, n_rows=N, n_ports=P, d_max=D, n_fields=F,
-        f_dest=F_DEST, f_beat=F_BEAT, n_vcs=n_vcs, masked=masked)
+    kernel = functools.partial(_fused_kernel, n_ports=P, d_max=D,
+                               n_fields=F, f_beat=F_BEAT)
     out_shapes = [
         jax.ShapeDtypeStruct((N, P * D * F), jnp.int32),   # fifo
         jax.ShapeDtypeStruct((N, P), jnp.int32),           # count
@@ -308,28 +262,16 @@ def fused_fabric_step_pallas(fifo, count, rr_ptr, oreg, oreg_v, lock_in,
         jax.ShapeDtypeStruct((N, P * F), jnp.int32),       # oreg
         jax.ShapeDtypeStruct((N, P), jnp.int32),           # oreg_v
         jax.ShapeDtypeStruct((N, P), jnp.int32),           # lock_in
-        jax.ShapeDtypeStruct((N, 1), jnp.int32),           # inj_ok
-        jax.ShapeDtypeStruct((N, 1), jnp.int32),           # deliver_valid
-        jax.ShapeDtypeStruct((N, F), jnp.int32),           # deliver_flit
-        jax.ShapeDtypeStruct((N, 1), jnp.int32),           # link_moves
     ]
     operands = [
-        fifo.reshape(N, P * D * F).astype(jnp.int32),
-        count.astype(jnp.int32), rr_ptr.astype(jnp.int32),
-        oreg.reshape(N, P * F).astype(jnp.int32),
-        oreg_v.astype(jnp.int32), lock_in.astype(jnp.int32),
-        inject_valid.astype(jnp.int32)[:, None],
-        inject_flit.astype(jnp.int32),
-        depth_rows.astype(jnp.int32)[:, None],
-    ]
-    if masked:
-        operands.append(link_mask_rows.astype(jnp.int32))
-    operands += [
-        nbr_rows.astype(jnp.int32), opp_rows.astype(jnp.int32),
-        route_rows.astype(jnp.int32), src_rows.astype(jnp.int32)]
+        fifo.reshape(N, P * D * F), count, rr_ptr,
+        oreg.reshape(N, P * F), oreg_v, lock_in, drain, out_port,
+        recv_valid, recv_flit.reshape(N, P * F), depth_rows[:, None]]
+    operands = [o.astype(jnp.int32) for o in operands]
     if not interpret and vmem_budget_bytes is not None:
-        est = 4 * (sum(math.prod(o.shape) for o in operands)
-                   + sum(math.prod(s.shape) for s in out_shapes))
+        # each 2-D int32 array occupies whole (8, 128) VMEM tiles
+        est = sum(4 * -(-r // 8) * 8 * -(-c // 128) * 128
+                  for r, c in [o.shape for o in operands + out_shapes])
         if est > vmem_budget_bytes:
             raise ValueError(
                 f"fused fabric kernel needs ~{est} bytes of VMEM for "
@@ -342,13 +284,10 @@ def fused_fabric_step_pallas(fifo, count, rr_ptr, oreg, oreg_v, lock_in,
                 f"depth), or split physical channels into separate "
                 f"sims — or raise vmem_budget_bytes if your core "
                 f"really has the headroom.")
-    (nfifo, ncount, nptr, noreg, noregv, nlock, injok, dv, dflit,
-     lm) = pl.pallas_call(kernel, out_shape=out_shapes,
-                          interpret=interpret)(*operands)
+    nfifo, ncount, nptr, noreg, noregv, nlock = pl.pallas_call(
+        kernel, out_shape=out_shapes, interpret=interpret)(*operands)
     return (nfifo.reshape(N, P, D, F), ncount, nptr,
-            noreg.reshape(N, P, F), noregv, nlock,
-            injok[:, 0].astype(jnp.bool_), dv[:, 0].astype(jnp.bool_),
-            dflit, lm[:, 0])
+            noreg.reshape(N, P, F), noregv, nlock)
 
 
 def router_arbiter_ref(out_port, beat, rr_ptr, oreg_free, lock_in):

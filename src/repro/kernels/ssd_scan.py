@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import compiler_params
 
 NEG_INF = -1e30
 
@@ -107,7 +106,7 @@ def ssd_pallas(x, dt, A_log, Bmat, Cmat, D, *, chunk=256, h0=None,
             jax.ShapeDtypeStruct((Bsz, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xt, dtt, A_log, Bt, Ct, D)

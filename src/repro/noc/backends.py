@@ -14,11 +14,17 @@ body.
   the channel axis),
 * ``"pallas"``       — same fabric step with phase-B arbitration
   replaced by the Pallas router-arbiter kernel
-  (``kernels/noc_router.py``), auto-interpreted off-TPU,
-* ``"pallas_fused"`` — the FULL one-cycle network update (drain +
-  neighbor push + arbitration + FIFO pop/push) in ONE Pallas kernel
-  over channel-folded router rows
+  (``kernels/noc_router.py``),
+* ``"pallas_fused"`` — the cross-row front of the cycle (drain,
+  neighbor push, injection, route lookup:
+  :func:`~repro.core.noc_sim.router.fabric_front`) in jnp, then the
+  row-local rest (arbitration + output-register and FIFO update) in
+  ONE Pallas kernel over channel-folded router rows
   (:func:`~repro.kernels.noc_router.fused_fabric_step_pallas`).
+
+Both Pallas backends compile for TPU v5e (guarded by
+``tests/test_tpu_compile.py``); they run in interpret mode on the CPU
+only, where they show correctness and nothing about speed.
 
 The protocol:
 
@@ -63,15 +69,13 @@ Register custom engines with :func:`register_backend`; select one with
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.noc_sim.router import (N_FIELDS, NetState, feeder_tables,
-                                       make_fabric_step)
+from repro.core.noc_sim.router import (N_FIELDS, NetState, fabric_front,
+                                       fabric_tables, make_fabric_step)
 from .topology import Topology
 
 __all__ = ["Network", "BACKENDS", "register_backend", "get_backend",
@@ -168,69 +172,40 @@ def _pallas_backend(topo: Topology, routing=None, faults=None) -> Network:
     return _vmapped_network(topo, routing, arbiter=arbiter, faults=faults)
 
 
-@functools.lru_cache(maxsize=64)
-def _fused_tables(topo: Topology, routing, n_ch: int, faults=None):
-    """Row-folded static tables for the fused kernel: channel ``c``'s
-    router ``r`` becomes row ``c*R + r``; neighbor/feeder indices are
-    offset into the row space so one kernel advances every channel.
-    ``routing`` (a hashable policy or None) selects the VC/plane-
-    expanded table set — the fold is oblivious to which.
-    Returned as *numpy* — this cache is often first populated inside a
-    jit trace, and caching jnp constants would leak tracers into later
-    traces."""
-    nbr, opp, route, _ = _resolve_tables(topo, routing, faults)
-    src_r, src_o = feeder_tables(nbr, opp)
-    R, P = nbr.shape
-    offs = (np.arange(n_ch) * R)[:, None, None]             # (C, 1, 1)
-    nbr_rows = np.where(nbr[None] >= 0, nbr[None] + offs,
-                        -1).reshape(n_ch * R, P)
-    opp_rows = np.tile(opp, (n_ch, 1))
-    route_rows = np.tile(route, (n_ch, 1))                  # (C*R, K*R)
-    src_rows = np.where(
-        src_r[None] >= 0,
-        (src_r[None] + offs) * P + src_o[None], -1).reshape(n_ch * R, P)
-    return (nbr_rows.astype(np.int32), opp_rows.astype(np.int32),
-            route_rows.astype(np.int32), src_rows.astype(np.int32))
-
-
 @register_backend("pallas_fused")
 def _pallas_fused_backend(topo: Topology, routing=None,
                           faults=None) -> Network:
     from repro.kernels.noc_router import fused_fabric_step_pallas
 
-    nbr, _, _, n_vcs = _resolve_tables(topo, routing, faults)
+    nbr, opp, route, n_vcs = _resolve_tables(topo, routing, faults)
     R, P = nbr.shape
-    masked = faults is not None
+    tables = fabric_tables(nbr, opp, route)
+
+    def front(state, inject_valid, inject_flit, depth, link_mask=None):
+        return fabric_front(state, inject_valid, inject_flit, depth,
+                            tables, n_vcs=n_vcs, link_mask=link_mask)
+
+    # the link mask is shared across channels (the fault is physical)
+    fronts = jax.vmap(front, in_axes=(0, 0, 0, 0, None)
+                      if faults is not None else (0, 0, 0, 0))
 
     def step(state: NetState, inject_valid, inject_flit, depths,
              *fault_args):
+        f = fronts(state, inject_valid, inject_flit, depths, *fault_args)
         C = state.count.shape[0]
-        D, F = state.fifo.shape[3], state.fifo.shape[4]
-        N = C * R
-        tables = _fused_tables(topo, routing, C, faults)
-        depth_rows = jnp.repeat(depths.astype(jnp.int32), R)
-        mask_rows = None
-        if masked:
-            (link_mask,) = fault_args                # (R, P), channel-shared
-            mask_rows = jnp.tile(link_mask, (C, 1))  # (N, P)
-        (fifo, count, rr_ptr, oreg, oreg_v, lock_in, inj_ok, dv, dflit,
-         lm_rows) = fused_fabric_step_pallas(
-            state.fifo.reshape(N, P, D, F),
-            state.count.reshape(N, P),
-            state.rr_ptr.reshape(N, P),
-            state.oreg.reshape(N, P, F),
-            state.oreg_v.reshape(N, P),
-            state.lock_in.reshape(N, P),
-            inject_valid.reshape(N), inject_flit.reshape(N, F),
-            depth_rows, *tables, n_vcs=n_vcs, link_mask_rows=mask_rows)
-        new_state = NetState(
-            fifo=fifo.reshape(C, R, P, D, F),
-            count=count.reshape(C, R, P),
-            rr_ptr=rr_ptr.reshape(C, R, P),
-            oreg=oreg.reshape(C, R, P, F),
-            oreg_v=(oreg_v > 0).reshape(C, R, P),
-            lock_in=lock_in.reshape(C, R, P))
-        return (new_state, inj_ok.reshape(C, R), dv.reshape(C, R),
-                dflit.reshape(C, R, F), lm_rows.reshape(C, R).sum(axis=1))
+
+        def rows(a):                     # (C, R, ...) -> (C*R, ...)
+            return a.reshape(C * R, *a.shape[2:])
+
+        out = fused_fabric_step_pallas(
+            *map(rows, state), rows(f.drain), rows(f.out_port),
+            rows(f.recv_valid), rows(f.recv_flit),
+            jnp.repeat(depths.astype(jnp.int32), R))
+        fifo, count, rr_ptr, oreg, oreg_v, lock_in = (
+            a.reshape(C, R, *a.shape[1:]) for a in out)
+        new_state = NetState(fifo=fifo, count=count, rr_ptr=rr_ptr,
+                             oreg=oreg, oreg_v=oreg_v > 0, lock_in=lock_in)
+        return (new_state, f.inj_ok, f.deliver_valid, f.deliver_flit,
+                f.link_moves)
 
     return Network(init=_stacked_init(R, P), step=step)

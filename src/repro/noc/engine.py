@@ -1129,10 +1129,6 @@ def _build_sim(spec: NocSpec, T: int, backend: str, d_max: int):
         return (service_lat[cls_of], mo, burst_beats[cls_of],
                 jitter[cls_of])
 
-    # donating the big schedule operands lets XLA alias them into the
-    # scan carry's workspace; CPU can't donate (it would only warn)
-    donate = () if jax.default_backend() == "cpu" else (0, 1, 2)
-
     def _run(times, dests, writes, service_lat, max_out, burst_beats,
              jitter, depths, fault_ops):
         state = SimState(network.init(n_ch, d_max),
@@ -1195,7 +1191,7 @@ def _build_sim(spec: NocSpec, T: int, backend: str, d_max: int):
         return raw
 
     if faulted:
-        @functools.partial(jax.jit, donate_argnums=donate)
+        @jax.jit
         def run(times, dests, writes, service_lat, max_out, burst_beats,
                 jitter, depths, ev_fail, ev_heal, timeout_cycles,
                 max_retries, backoff_base):
@@ -1204,7 +1200,7 @@ def _build_sim(spec: NocSpec, T: int, backend: str, d_max: int):
                         (ev_fail, ev_heal, timeout_cycles, max_retries,
                          backoff_base))
     else:
-        @functools.partial(jax.jit, donate_argnums=donate)
+        @jax.jit
         def run(times, dests, writes, service_lat, max_out, burst_beats,
                 jitter, depths):
             return _run(times, dests, writes, service_lat, max_out,

@@ -67,11 +67,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh as _DeviceMesh, PartitionSpec as P
 
-from repro.core.noc_sim.router import (F_BEAT, F_DEST, N_FIELDS, NO_PORT,
-                                       NetState, arbiter_jnp,
+from repro.core.noc_sim.router import (FabricTables, NetState,
+                                       fabric_front, fabric_update,
                                        feeder_tables)
 from repro.dist.backend import halo_permute
 from .api import (_check_dead_traffic, _depths, _dyn_scalars, _fault_ops,
@@ -177,8 +176,8 @@ def compiled_farm_sweep(spec: NocSpec, T: int, devices: int,
                                        *((None,) * n_fops)))
     in_specs = ((P(SPEC_AXIS),) * 3 + (P(),) * 4 + (P(SPEC_AXIS),)
                 + (P(),) * n_fops)
-    fn = jax.jit(shard_map(vmapped, mesh=mesh, in_specs=in_specs,
-                           out_specs=P(SPEC_AXIS), check_rep=False))
+    fn = jax.jit(jax.shard_map(vmapped, mesh=mesh, in_specs=in_specs,
+                               out_specs=P(SPEC_AXIS), check_vma=False))
     return _cache_put(partition, key, fn)
 
 
@@ -300,9 +299,6 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
     mesh = _device_mesh(n_shards, ROW_AXIS)
     # extended row index space per shard: [north halo (nx rows) |
     # local (R_l rows) | south halo (nx rows)]
-    R_ext = R_l + 2 * nx
-    PORT_L = Pn - 1
-    n_phys = (Pn - 1) // n_vcs
 
     # global tables as replicated jnp constants; each shard slices its
     # own R_l-row window at trace time (hoisted out of the cycle scan)
@@ -324,7 +320,7 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
         # every neighbor/feeder of a local row lies within one boundary
         # strip, so its extended index is one affine: north halo rows
         # land in [0, nx), local in [nx, nx + R_l), south in
-        # [nx + R_l, R_ext).  Torus wrap links need the mod (with n=1 a
+        # [nx + R_l, R_l + 2 nx).  Torus wrap links need the mod (with n=1 a
         # wrapped neighbor then resolves into the identity self-halo);
         # a mesh has no wrap links, and must NOT mod — with n=1 the
         # affine of a local bottom-strip row exceeds R_g and the mod
@@ -333,10 +329,11 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
             off = g - base + nx
             return off % R_g if wrap else off
 
-        nbr_ext = jnp.where(nbr_l >= 0, ext(nbr_l), -1)
         has_feed = srcr_l >= 0
-        src_flat = jnp.where(has_feed, ext(srcr_l) * Pn + srco_l, 0)
-        return nbr_ext, opp_l, route_l, has_feed, src_flat
+        return FabricTables(
+            nbr=jnp.where(nbr_l >= 0, ext(nbr_l), -1), opp=opp_l,
+            route=route_l, has_feed=has_feed,
+            src_flat=jnp.where(has_feed, ext(srcr_l) * Pn + srco_l, 0))
 
     def _with_halo(x):
         """(R_l, ...) local rows -> (R_ext, ...) with both boundary
@@ -348,90 +345,17 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
                              wrap=wrap)
         return jnp.concatenate([north, x, south], axis=0)
 
-    def _make_net_step(nbr_ext, opp_l, route_l, has_feed, src_flat):
+    def _make_net_step(tables: FabricTables):
         """The row-local analogue of
-        :func:`~repro.core.noc_sim.router.make_fabric_step`: identical
-        phase structure, with the two cross-row gathers (downstream
+        :func:`~repro.core.noc_sim.router.make_fabric_step`: the same
+        two phases, with the front's cross-row gathers (downstream
         occupancy, neighbor push) reading the halo-extended arrays."""
-        r_idx = jnp.arange(R_l)
-
-        def serialize_drain(ready):
-            if n_vcs == 1:
-                return ready
-            e = ready[:, :Pn - 1].reshape(R_l, n_phys, n_vcs)
-            rank = jnp.where(e, jnp.arange(n_vcs)[None, None, :], -1)
-            win = e & (rank == jnp.max(rank, axis=2, keepdims=True))
-            return jnp.concatenate(
-                [win.reshape(R_l, Pn - 1), ready[:, Pn - 1:]], axis=1)
-
         def one(state: NetState, inject_valid, inject_flit, depth):
-            heads = state.fifo[:, :, 0, :]
-            head_valid = state.count > 0
-
-            # phase A: drain — backpressure reads the *halo-extended*
-            # cycle-start occupancy (registered, like the local gather)
-            count_ext = _with_halo(state.count)            # (R_ext, P)
-            ds_count = count_ext[jnp.clip(nbr_ext, 0, R_ext - 1), opp_l]
-            can_drain = jnp.where(
-                jnp.arange(Pn)[None, :] == PORT_L, True,
-                (nbr_ext >= 0) & (ds_count < depth))
-            drain = serialize_drain(state.oreg_v & can_drain)
-
-            deliver_valid = drain[:, PORT_L]
-            deliver_flit = state.oreg[:, PORT_L, :]
-
-            # neighbor push: the feeder gather reads halo-extended
-            # drain decisions + output registers
-            drain_ext = _with_halo(drain)                  # (R_ext, P)
-            oreg_ext = _with_halo(state.oreg)              # (R_ext, P, F)
-            recv_valid = has_feed & drain_ext.reshape(-1)[src_flat]
-            recv_flit = jnp.where(
-                recv_valid[:, :, None],
-                oreg_ext.reshape(-1, N_FIELDS)[src_flat], 0)
-
-            local_ready = state.count[:, PORT_L] < depth
-            inj_ok = inject_valid & local_ready
-            recv_valid = recv_valid.at[:, PORT_L].set(inj_ok)
-            recv_flit = recv_flit.at[:, PORT_L].set(
-                jnp.where(inj_ok[:, None], inject_flit, 0))
-
-            # phase B: arbitration (row-local; dest ids are global, the
-            # local route-table slice maps them to output ports)
-            oreg_free = (~state.oreg_v) | drain
-            out_port = route_l[r_idx[:, None], heads[:, :, F_DEST]]
-            out_port = jnp.where(head_valid, out_port, NO_PORT)
-            winner, pop, new_ptr, new_lock = arbiter_jnp(
-                out_port, heads[:, :, F_BEAT], state.rr_ptr, oreg_free,
-                state.lock_in)
-
-            any_grant = winner >= 0
-            flit_to_oreg = heads[r_idx[:, None], jnp.clip(winner, 0)]
-            new_oreg_v = (state.oreg_v & ~drain) | any_grant
-            new_oreg = jnp.where(any_grant[:, :, None], flit_to_oreg,
-                                 state.oreg)
-
-            D = state.fifo.shape[2]
-            shifted = jnp.concatenate(
-                [state.fifo[:, :, 1:, :],
-                 jnp.zeros_like(state.fifo[:, :, :1, :])], axis=2)
-            fifo = jnp.where(pop[:, :, None, None], shifted, state.fifo)
-            count = state.count - pop.astype(jnp.int32)
-
-            slot = jnp.clip(count, 0, D - 1)
-            write = recv_valid & (count < depth)
-            onehot_slot = jax.nn.one_hot(slot, D, dtype=jnp.bool_)
-            sel = write[:, :, None] & onehot_slot
-            fifo = jnp.where(sel[..., None], recv_flit[:, :, None, :],
-                             fifo)
-            count = count + write.astype(jnp.int32)
-
-            new_state = NetState(fifo=fifo, count=count, rr_ptr=new_ptr,
-                                 oreg=new_oreg, oreg_v=new_oreg_v,
-                                 lock_in=new_lock)
-            link_moves = jnp.sum(drain.astype(jnp.int32)
-                                 * (jnp.arange(Pn)[None, :] != PORT_L))
-            return (new_state, inj_ok, deliver_valid, deliver_flit,
-                    link_moves)
+            front = fabric_front(state, inject_valid, inject_flit, depth,
+                                 tables, n_vcs=n_vcs, ext=_with_halo)
+            return (fabric_update(state, front, depth), front.inj_ok,
+                    front.deliver_valid, front.deliver_flit,
+                    front.link_moves)
 
         return jax.vmap(one, in_axes=(0, 0, 0, 0))
 
@@ -453,7 +377,7 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
     def sharded(times, dests, writes, service_lat, max_out, burst_beats,
                 jitter, depths):
         # local shapes: times/dests/writes (n_lanes, R_l, T)
-        net_step = _make_net_step(*_local_tables())
+        net_step = _make_net_step(_local_tables())
         step = make_step(spec, plan, T, net_step, shard=sh)
         state = SimState(_stacked_init(R_l, Pn)(n_ch, d_max),
                          init_ni(R_l, plan, spec.resp_q_cap),
@@ -495,8 +419,8 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
         }
 
     in_specs = ((P(None, ROW_AXIS),) * 3 + (P(),) * 5)
-    smfn = jax.jit(shard_map(sharded, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(ROW_AXIS), check_rep=False))
+    smfn = jax.jit(jax.shard_map(sharded, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(ROW_AXIS), check_vma=False))
 
     def run(times, dests, writes, service_lat, max_out, burst_beats,
             jitter, depths):

@@ -376,19 +376,18 @@ def test_vmem_budget_raises_with_estimate():
     def z(*s):
         return jnp.zeros(s, jnp.int32)
 
-    args = (z(N, P, D, F), z(N, P), z(N, P), z(N, P, F), z(N, P),
-            z(N, P), z(N), z(N, F), jnp.full((N,), D, jnp.int32),
-            z(N, P), z(N, P), z(N, N), z(N, P))
+    def operands(n):
+        return (z(n, P, D, F), z(n, P), z(n, P), z(n, P, F), z(n, P),
+                z(n, P), z(n, P), z(n, P), z(n, P), z(n, P, F),
+                jnp.full((n,), D, jnp.int32))
+
     with pytest.raises(ValueError, match=r"bytes of VMEM .*RowShard"):
-        fused_fabric_step_pallas(*args, interpret=False)
+        fused_fabric_step_pallas(*operands(N), interpret=False)
     # tightening the budget trips the check on any size; interpret mode
     # never engages it (a small fabric still runs)
-    n = 8
-    small = (z(n, P, D, F), z(n, P), z(n, P), z(n, P, F), z(n, P),
-             z(n, P), z(n), z(n, F), jnp.full((n,), D, jnp.int32),
-             z(n, P), z(n, P), z(n, n), z(n, P))
+    small = operands(8)
     with pytest.raises(ValueError, match="VMEM"):
         fused_fabric_step_pallas(*small, interpret=False,
                                  vmem_budget_bytes=64)
     out = fused_fabric_step_pallas(*small, interpret=True)
-    assert out[0].shape == (n, P, D, F)
+    assert out[0].shape == (8, P, D, F)
