@@ -222,6 +222,7 @@ def serialize_drain(ready: jax.Array, n_vcs: int) -> jax.Array:
                            axis=1)
 
 
+@jax.named_scope("fabric_front")
 def fabric_front(state: NetState, inject_valid: jax.Array,
                  inject_flit: jax.Array, depth: jax.Array,
                  tables: FabricTables, *, n_vcs: int = 1,
@@ -275,6 +276,7 @@ def fabric_front(state: NetState, inject_valid: jax.Array,
                  link_moves=link_moves)
 
 
+@jax.named_scope("fabric_update")
 def fabric_update(state: NetState, front: Front, depth: jax.Array,
                   arbiter=None) -> NetState:
     """Phase B (arbitration into freed output registers) and the input

@@ -34,14 +34,21 @@ flow->channel protocol order and ROB/credit budgets, and reports named
 route-table checks; ``simulate(..., verify="full")`` rejects
 deadlock-prone specs before stepping, and ``python -m
 repro.noc.analyze --all-presets`` is the CI gate.
+
+Observability: ``span_stats()`` counts every API call's host phases
+(``noc.verify``, ``noc.schedules``, ``noc.build``, ``noc.dispatch``,
+``noc.wait``, ``noc.results`` under one ``noc.simulate``/``noc.sweep``
+span) with their seconds, the same spans a ``jax.profiler`` trace shows
+with a ``call=<id>`` argument; the scan step's device ops run under the
+named scopes of :data:`PHASES`.
 """
 from .api import (jitter_table, simulate, simulate_batch,  # noqa: F401
                   simulate_schedules, stack_schedules, sweep)
 from .backends import (get_backend, list_backends,  # noqa: F401
                        register_backend)
-from .engine import (FlowPlan, build_channel_plan,  # noqa: F401
+from .engine import (PHASES, FlowPlan, build_channel_plan,  # noqa: F401
                      build_flow_plan, compiled_sim, sim_cache_clear,
-                     sim_cache_stats)
+                     sim_cache_stats, span_stats, span_stats_clear)
 from .farm import (RowShard, farm_batch, merge_spec,  # noqa: F401
                    partition_spec)
 from .faults import (FaultModel, UnroutableCutError,  # noqa: F401

@@ -33,10 +33,10 @@ from dataclasses import replace
 from typing import Mapping, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from .engine import BIG, JITTER_TABLE_LEN, compiled_sim
+from .engine import (BIG, JITTER_TABLE_LEN, _depth_normalized, call_span,
+                     compiled_program, compiled_sim, span)
 from .result import SimResult
 from .spec import NocSpec
 from .workload import Workload
@@ -48,8 +48,25 @@ __all__ = ["simulate", "simulate_batch", "simulate_schedules", "sweep",
 def _verify(spec: NocSpec, verify: str) -> None:
     """Static-analysis gate (lazy import: repro.noc.analyze depends on
     this package's spec/engine modules)."""
+    if verify == "off":
+        return
     from .analyze import verify_spec
-    verify_spec(spec, verify)
+    with span("noc.verify"):
+        verify_spec(spec, verify)
+
+
+def run_program(spec: NocSpec, prog, args, fold=None) -> SimResult:
+    """One call of a compiled program, each step under its span:
+    ``noc.dispatch`` (copies to the device, enqueue), ``noc.wait``
+    (until the device program ends) and ``noc.results`` (``fold`` of
+    the raw leaves, if given, copies to the host and the
+    ``SimResult``)."""
+    with span("noc.dispatch"):
+        raw = prog(*args)
+    with span("noc.wait"):
+        raw = jax.block_until_ready(raw)
+    with span("noc.results"):
+        return SimResult.from_raw(spec, raw if fold is None else fold(raw))
 
 
 def _split_streams(cls_name, t, d, w, s, S):
@@ -268,22 +285,46 @@ def simulate_schedules(spec: NocSpec,
     halo exchange of boundary-link state — flit-for-flit identical to
     the single-device engine; requires a plain Mesh/Torus, the
     ``jnp`` backend and a fault-free spec."""
-    _verify(spec, verify)
-    times, dests, writes = stack_schedules(spec, schedules)
-    _check_dead_traffic(spec, times, dests)
-    sl, mo, bb = _dyn_scalars(spec, service_lat, max_outstanding,
-                              burst_beats)
-    jt = jitter_table(spec, service_jitter, seed=jitter_seed,
-                      service_lat=service_lat)
-    fops = _fault_ops(spec, timeout_cycles, max_retries, backoff_base)
-    if shard is not None:
-        from .farm import compiled_rowshard_sim
-        fn = compiled_rowshard_sim(spec, times.shape[-1], shard,
-                                   backend=backend)
-    else:
-        fn = compiled_sim(spec, times.shape[-1], backend)
-    raw = fn(times, dests, writes, sl, mo, bb, jt, _depths(spec), *fops)
-    return SimResult.from_raw(spec, raw)
+    return _simulate(spec, lambda: schedules, service_lat=service_lat,
+                     max_outstanding=max_outstanding,
+                     burst_beats=burst_beats, service_jitter=service_jitter,
+                     jitter_seed=jitter_seed, timeout_cycles=timeout_cycles,
+                     max_retries=max_retries, backoff_base=backoff_base,
+                     backend=backend, verify=verify, shard=shard)
+
+
+def _simulate(spec: NocSpec, make_schedules, *, service_lat,
+              max_outstanding, burst_beats, service_jitter, jitter_seed,
+              timeout_cycles, max_retries, backoff_base, backend, verify,
+              shard) -> SimResult:
+    """One experiment as one ``noc.simulate`` call span; the schedules
+    are made (``make_schedules()``) inside its ``noc.schedules``."""
+    with call_span("noc.simulate"):
+        _verify(spec, verify)
+        with span("noc.schedules"):
+            times, dests, writes = stack_schedules(spec, make_schedules())
+            _check_dead_traffic(spec, times, dests)
+            sl, mo, bb = _dyn_scalars(spec, service_lat, max_outstanding,
+                                      burst_beats)
+            jt = jitter_table(spec, service_jitter, seed=jitter_seed,
+                              service_lat=service_lat)
+            fops = _fault_ops(spec, timeout_cycles, max_retries,
+                              backoff_base)
+            args = (times, dests, writes, sl, mo, bb, jt, _depths(spec),
+                    *fops)
+        T = times.shape[-1]
+        key_spec, _ = _depth_normalized(spec, None)
+        if shard is None:
+            prog = compiled_program(
+                ("sim", key_spec, T, backend),
+                lambda: compiled_sim(spec, T, backend), args)
+            return run_program(spec, prog, args)
+        from .farm import compiled_rowshard_sim, fold_shards
+        prog = compiled_program(
+            ("rowshard", key_spec, T, shard, backend),
+            lambda: compiled_rowshard_sim(spec, T, shard, backend=backend),
+            args)
+        return run_program(spec, prog, args, fold=fold_shards)
 
 
 def simulate(spec: NocSpec, workload: Workload, *,
@@ -308,16 +349,13 @@ def simulate(spec: NocSpec, workload: Workload, *,
     ``backoff_base``) require a spec with a FaultModel.
     ``shard=RowShard(n)`` row-shards one big fabric across ``n`` local
     devices (:mod:`repro.noc.farm` tier b), flit-for-flit identical."""
-    return simulate_schedules(spec, workload.schedules(spec),
-                              service_lat=service_lat,
-                              max_outstanding=max_outstanding,
-                              burst_beats=burst_beats,
-                              service_jitter=service_jitter,
-                              jitter_seed=jitter_seed,
-                              timeout_cycles=timeout_cycles,
-                              max_retries=max_retries,
-                              backoff_base=backoff_base, backend=backend,
-                              verify=verify, shard=shard)
+    return _simulate(spec, lambda: workload.schedules(spec),
+                     service_lat=service_lat,
+                     max_outstanding=max_outstanding,
+                     burst_beats=burst_beats, service_jitter=service_jitter,
+                     jitter_seed=jitter_seed, timeout_cycles=timeout_cycles,
+                     max_retries=max_retries, backoff_base=backoff_base,
+                     backend=backend, verify=verify, shard=shard)
 
 
 def simulate_batch(spec: NocSpec, workloads: Sequence[Workload], *,
@@ -339,10 +377,27 @@ def simulate_batch(spec: NocSpec, workloads: Sequence[Workload], *,
     explicit (N, n_cls) form to be unambiguous.  Returns a SimResult
     whose arrays carry a leading sweep axis.
     """
-    n = len(workloads)
-    if n == 0:
+    if not workloads:
         raise ValueError("empty sweep")
-    _verify(spec, verify)
+    with call_span("noc.sweep"):
+        _verify(spec, verify)
+        with span("noc.schedules"):
+            args, in_axes = _batch_operands(
+                spec, workloads, service_lat, max_outstanding, burst_beats,
+                service_jitter, jitter_seed)
+        T = args[0].shape[-1]
+        prog = compiled_program(
+            ("sim", _depth_normalized(spec, None)[0], T, backend),
+            lambda: compiled_sim(spec, T, backend), args, in_axes)
+        return run_program(spec, prog, args)
+
+
+def _batch_operands(spec: NocSpec, workloads, service_lat,
+                    max_outstanding, burst_beats, service_jitter,
+                    jitter_seed) -> tuple[tuple, tuple]:
+    """The operands of :func:`simulate_batch`'s vmapped call and their
+    ``in_axes``."""
+    n = len(workloads)
     fops = _fault_ops(spec)    # fault knobs stay spec-declared per batch
     per_point = [wl.schedules(spec) for wl in workloads]
     T = max(max(np.asarray(t).reshape(spec.n_routers, -1).shape[1]
@@ -403,14 +458,9 @@ def simulate_batch(spec: NocSpec, workloads: Sequence[Workload], *,
 
     for t, d in ((times[i], dests[i]) for i in range(n)):
         _check_dead_traffic(spec, t, d)
-    fn = compiled_sim(spec, T, backend)
-    raw = jax.vmap(fn, in_axes=(0, 0, 0, sl_ax, mo_ax, bb_ax, jt_ax,
-                                None, *((None,) * len(fops))))(
-        jnp.asarray(times), jnp.asarray(dests), jnp.asarray(writes),
-        jnp.asarray(sl), jnp.asarray(mo), jnp.asarray(bb),
-        jnp.asarray(jt), jnp.asarray(_depths(spec)),
-        *(jnp.asarray(x) for x in fops))
-    return SimResult.from_raw(spec, raw)
+    return ((times, dests, writes, sl, mo, bb, jt, _depths(spec), *fops),
+            (0, 0, 0, sl_ax, mo_ax, bb_ax, jt_ax, None,
+             *((None,) * len(fops))))
 
 
 def _strip_depths(spec: NocSpec) -> NocSpec:
@@ -425,29 +475,28 @@ def _batch_depth_sweep(specs: Sequence[NocSpec], wls: Sequence[Workload],
     """Vmap points that differ only in FIFO depths through ONE
     padded-depth compilation (depth masked against the group max)."""
     base = specs[0]
-    per_point = [wl.schedules(s) for s, wl in zip(specs, wls)]
-    T = max(max(np.asarray(t).reshape(base.n_routers, -1).shape[1]
-                for t, *_ in sched.values()) for sched in per_point)
-    stacked = [stack_schedules(s, sched, T=T)
-               for s, sched in zip(specs, per_point)]
-    times = np.stack([t for t, _, _ in stacked])
-    dests = np.stack([d for _, d, _ in stacked])
-    writes = np.stack([w for _, _, w in stacked])
-    sl, mo, bb = _dyn_scalars(base, None, None, None)
-    jt = jitter_table(base)
-    fops = _fault_ops(base)
-    for t, d in ((times[i], dests[i]) for i in range(len(specs))):
-        _check_dead_traffic(base, t, d)
-    depths = np.stack([_depths(s) for s in specs])         # (n, n_ch)
-    fn = compiled_sim(base, T, backend,
-                      max_depth=int(depths.max()))
-    raw = jax.vmap(fn, in_axes=(0, 0, 0, None, None, None, None, 0,
-                                *((None,) * len(fops))))(
-        jnp.asarray(times), jnp.asarray(dests), jnp.asarray(writes),
-        jnp.asarray(sl), jnp.asarray(mo), jnp.asarray(bb),
-        jnp.asarray(jt), jnp.asarray(depths),
-        *(jnp.asarray(x) for x in fops))
-    return SimResult.from_raw(base, raw)
+    with span("noc.schedules"):
+        per_point = [wl.schedules(s) for s, wl in zip(specs, wls)]
+        T = max(max(np.asarray(t).reshape(base.n_routers, -1).shape[1]
+                    for t, *_ in sched.values()) for sched in per_point)
+        stacked = [stack_schedules(s, sched, T=T)
+                   for s, sched in zip(specs, per_point)]
+        times = np.stack([t for t, _, _ in stacked])
+        dests = np.stack([d for _, d, _ in stacked])
+        writes = np.stack([w for _, _, w in stacked])
+        sl, mo, bb = _dyn_scalars(base, None, None, None)
+        jt = jitter_table(base)
+        fops = _fault_ops(base)
+        for t, d in ((times[i], dests[i]) for i in range(len(specs))):
+            _check_dead_traffic(base, t, d)
+        depths = np.stack([_depths(s) for s in specs])     # (n, n_ch)
+        args = (times, dests, writes, sl, mo, bb, jt, depths, *fops)
+    d_max = int(depths.max())
+    prog = compiled_program(
+        ("sim", _depth_normalized(base, d_max)[0], T, backend),
+        lambda: compiled_sim(base, T, backend, max_depth=d_max), args,
+        (0, 0, 0, None, None, None, None, 0, *((None,) * len(fops))))
+    return run_program(base, prog, args)
 
 
 def sweep(points: Sequence[tuple[NocSpec, Workload]], *,
@@ -474,6 +523,12 @@ def sweep(points: Sequence[tuple[NocSpec, Workload]], *,
     ``verify`` runs the :mod:`repro.noc.analyze` gate once per distinct
     spec before any simulation (the deadlock proof is lru-cached per
     (topology, routing), so a 70-point sweep pays it once)."""
+    with call_span("noc.sweep"):
+        return _sweep(points, backend=backend, pad_depths=pad_depths,
+                      verify=verify, devices=devices)
+
+
+def _sweep(points, *, backend, pad_depths, verify, devices):
     for s in {spec for spec, _ in points}:
         _verify(s, verify)
     groups: dict[NocSpec, list[int]] = {}
@@ -484,8 +539,10 @@ def sweep(points: Sequence[tuple[NocSpec, Workload]], *,
     for idxs in groups.values():
         specs = [points[i][0] for i in idxs]
         wls = [points[i][1] for i in idxs]
+        # every spec is verified above: the groups' calls skip the gate
         if len(idxs) == 1:
-            out[idxs[0]] = simulate(specs[0], wls[0], backend=backend)
+            out[idxs[0]] = simulate(specs[0], wls[0], backend=backend,
+                                    verify="off")
         elif devices is not None:
             from .farm import farm_batch
             batched = farm_batch(specs, wls, devices, backend)
@@ -494,7 +551,8 @@ def sweep(points: Sequence[tuple[NocSpec, Workload]], *,
                 # under the group's depth-padded base spec)
                 out[i] = replace(batched.point(j), spec=specs[j])
         elif all(s == specs[0] for s in specs):
-            batched = simulate_batch(specs[0], wls, backend=backend)
+            batched = simulate_batch(specs[0], wls, backend=backend,
+                                     verify="off")
             for j, i in enumerate(idxs):
                 out[i] = batched.point(j)
         else:

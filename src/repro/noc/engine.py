@@ -86,8 +86,12 @@ operands so ``jax.vmap`` batches whole sweeps in one jit.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+import itertools
 import threading
+import time
 from collections import OrderedDict
 from dataclasses import replace
 from typing import NamedTuple
@@ -111,6 +115,11 @@ N_QFIELDS = 6
 # static length of the seeded per-class jitter table (prime, so txn-id
 # indexing doesn't alias power-of-two burst/count periodicities)
 JITTER_TABLE_LEN = 251
+
+# the scan step's phases, in cycle order: every operation of the step
+# runs under exactly one of these outermost ``jax.named_scope``s, so a
+# profiler trace attributes device time per phase by op name
+PHASES = ("noc.ni_issue", "noc.fabric", "noc.ni_rings", "noc.stats")
 
 
 class ShardInfo(NamedTuple):
@@ -484,7 +493,8 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
         lane_ids = jnp.arange(n_cls)
         p_ids = jnp.arange(p_cap)
 
-    def step(dyn, state: SimState, _):
+    def cycle(phase, dyn, state: SimState):
+        phase("noc.ni_issue")
         times, dests = dyn["times"], dyn["dests"]     # (R, n_cls, T)
         writes = dyn["writes"]                        # (R, n_cls, T)
         service_lat = dyn["service_lat"]              # (n_cls,)
@@ -741,6 +751,7 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
         # ---- ONE stacked fabric step for every channel ------------------
         iv = jnp.stack(iv_cols)                        # (n_ch, R)
         iflit = jnp.stack(flit_cols)                   # (n_ch, R, F)
+        phase("noc.fabric")
         if faulted:
             net, ok_ch, dv_ch, df_ch, lm = net_step(
                 state.net, iv, iflit, dyn["depths"], link_mask)
@@ -750,6 +761,7 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
 
         # per-VC input-FIFO occupancy (non-local ports; virtual port
         # q = link * n_vcs + vc under the routing policy's table fold)
+        phase("noc.stats")
         occ = jnp.sum(net.count[:, :, :-1].reshape(
             net.count.shape[0], R, -1, n_vcs), axis=(1, 2))   # (n_ch, V)
         if shard is not None:      # fabric-wide occupancy, every shard
@@ -758,6 +770,7 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
         vc_occ_max = jnp.maximum(state.vc_occ_max, occ)
 
         # ---- pointer / ROB / ring-head updates --------------------------
+        phase("noc.ni_rings")
         inj_ar = jnp.stack(
             [ok_ch[int(pa.ar_ch[i])] & sel_ar[i]
              if i in sel_ar else false for i in range(n_cls)], axis=1)
@@ -893,6 +906,7 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
         ni = ni._replace(rq=rq, wq=wq, rq_tail=ni.rq_tail + tail_inc)
 
         # ---- per-class per-direction metrics, vectorized ----------------
+        phase("noc.stats")
         last_r = is_r & (df_r[..., F_BEAT] <= 1)
         if faulted:
             # completion gating through the pending table: only a
@@ -970,7 +984,17 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
         return SimState(net, ni, now + 1, new_moves, cur,
                         jnp.maximum(state.max_stall, cur),
                         vc_occ_sum, vc_occ_max,
-                        fs if faulted else state.fs), None
+                        fs if faulted else state.fs)
+
+    def step(dyn, state: SimState, _):
+        # one named scope at a time: phase(name) leaves the current
+        # phase's scope and enters the next one's (metadata only — the
+        # traced computation is the same with or without the scopes)
+        with contextlib.ExitStack() as scope:
+            def phase(name):
+                scope.close()
+                scope.enter_context(jax.named_scope(name))
+            return cycle(phase, dyn, state), None
 
     return step
 
@@ -1034,7 +1058,113 @@ def _cache_put(partition: str, key, fn):
 def sim_cache_clear() -> None:
     with _cache_lock:
         _caches.clear()
+        _programs.clear()
         _stats.update(hits=0, misses=0, evictions=0)
+
+
+# --------------------------------------------------------------------- #
+# host spans: profiler annotations plus a process-wide table
+# --------------------------------------------------------------------- #
+_spans: dict[str, list] = {}            # name -> [count, seconds]
+_span_lock = threading.Lock()
+_call_ids = itertools.count(1)
+_call: contextvars.ContextVar[int | None] = contextvars.ContextVar(
+    "repro_noc_call", default=None)
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """Time the block as host span ``name``: a
+    ``jax.profiler.TraceAnnotation`` on the profiler's clock, carrying
+    ``call=<id>`` of the API call it belongs to, and one count plus its
+    seconds in :func:`span_stats`."""
+    call = _call.get()
+    t = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(
+                name, **({} if call is None else {"call": call})):
+            yield
+    finally:
+        dt = time.perf_counter() - t
+        with _span_lock:
+            entry = _spans.setdefault(name, [0, 0.0])
+            entry[0] += 1
+            entry[1] += dt
+
+
+@contextlib.contextmanager
+def call_span(name: str):
+    """The parent span of one API call (``noc.simulate``/``noc.sweep``)
+    under a fresh per-process call id, which every span inside it
+    carries.  A call made inside another (a sweep's one-point group
+    runs through ``simulate``) stays part of the outer call: no second
+    parent, no new id."""
+    if _call.get() is not None:
+        yield
+        return
+    token = _call.set(next(_call_ids))
+    try:
+        with span(name):
+            yield
+    finally:
+        _call.reset(token)
+
+
+def span_stats() -> dict:
+    """Host time of the API's phases since process start (or
+    :func:`span_stats_clear`): ``{name: {"count": n, "seconds": s}}``.
+    Every ``simulate``/``sweep`` call is one ``noc.simulate`` /
+    ``noc.sweep`` span holding ``noc.verify``, ``noc.schedules``,
+    ``noc.build`` (only where a program had to be built: simulator,
+    trace, lowering, compile or cache load), ``noc.dispatch`` (copies to
+    the device and enqueue), ``noc.wait`` (until the device program
+    ends) and ``noc.results`` (copies to the host, ``SimResult``)."""
+    with _span_lock:
+        return {k: {"count": c, "seconds": s}
+                for k, (c, s) in _spans.items()}
+
+
+def span_stats_clear() -> None:
+    with _span_lock:
+        _spans.clear()
+
+
+# --------------------------------------------------------------------- #
+# compiled programs: a simulator lowered for one argument layout
+# --------------------------------------------------------------------- #
+_programs: OrderedDict = OrderedDict()
+
+
+def compiled_program(key, get, args, in_axes=None):
+    """The program that runs ``args``: the cached jitted simulator
+    ``get()`` returns (vmapped over ``in_axes`` when given), lowered and
+    compiled for these argument shapes.  ``key`` names the simulator
+    (the arguments of the ``get`` call); with the shapes it keys a cache
+    of programs beside the simulator cache, so the vmapped form is
+    traced once, not on every call.  Everything a call has to build —
+    the simulator, the trace, lowering and compilation (or a persistent
+    cache load) — runs under one ``noc.build`` span; a call that finds
+    its program opens none, and ``get()`` counts the simulator cache's
+    hit as before."""
+    pkey = (key, in_axes,
+            tuple((np.shape(a), np.result_type(a)) for a in args))
+    with _cache_lock:
+        prog = _programs.get(pkey)
+        if prog is not None:
+            _programs.move_to_end(pkey)
+    if prog is not None:
+        get()
+        return prog
+    with span("noc.build"):
+        fn = get()
+        prog = fn if in_axes is None \
+            else jax.jit(jax.vmap(fn, in_axes=in_axes))
+        prog.lower(*args).compile()
+    with _cache_lock:
+        _programs[pkey] = prog
+        while len(_programs) > SIM_CACHE_MAXSIZE:
+            _programs.popitem(last=False)
+    return prog
 
 
 def _depth_normalized(spec: NocSpec, max_depth: int | None):

@@ -77,14 +77,14 @@ from .api import (_check_dead_traffic, _depths, _dyn_scalars, _fault_ops,
                   _strip_depths, jitter_table, stack_schedules)
 from .backends import _resolve_tables, _stacked_init
 from .engine import (BIG, ShardInfo, SimState, _cache_get, _cache_put,
-                     _depth_normalized, build_flow_plan, compiled_sim,
-                     init_ni, make_step)
+                     _depth_normalized, build_flow_plan, compiled_program,
+                     compiled_sim, init_ni, make_step, span)
 from .result import SimResult
 from .spec import NocSpec
 from .topology import Mesh, Torus
 
 __all__ = ["RowShard", "partition_spec", "merge_spec", "farm_batch",
-           "compiled_farm_sweep", "compiled_rowshard_sim"]
+           "compiled_farm_sweep", "compiled_rowshard_sim", "fold_shards"]
 
 ROW_AXIS = "rows"          # tier (b) shard_map axis name
 SPEC_AXIS = "specs"        # tier (a) shard_map axis name
@@ -189,40 +189,42 @@ def farm_batch(specs: Sequence[NocSpec], wls, devices: int,
     group up to a device multiple by repeating the last point and
     slices the pad off the gathered raw, so results keep the exact
     batched shape of the single-device path."""
+    from .api import run_program
     base = specs[0]
-    per_point = [wl.schedules(s) for s, wl in zip(specs, wls)]
-    T = max(max(np.asarray(t).reshape(base.n_routers, -1).shape[1]
-                for t, *_ in sched.values()) for sched in per_point)
-    stacked = [stack_schedules(s, sched, T=T)
-               for s, sched in zip(specs, per_point)]
-    times = np.stack([t for t, _, _ in stacked])       # (n, n_lanes, R, T)
-    dests = np.stack([d for _, d, _ in stacked])
-    writes = np.stack([w for _, _, w in stacked])
-    sl, mo, bb = _dyn_scalars(base, None, None, None)
-    jt = jitter_table(base)
-    fops = _fault_ops(base)
-    for i in range(len(specs)):
-        _check_dead_traffic(base, times[i], dests[i])
-    depths = np.stack([_depths(s) for s in specs])     # (n, n_ch)
+    with span("noc.schedules"):
+        per_point = [wl.schedules(s) for s, wl in zip(specs, wls)]
+        T = max(max(np.asarray(t).reshape(base.n_routers, -1).shape[1]
+                    for t, *_ in sched.values()) for sched in per_point)
+        stacked = [stack_schedules(s, sched, T=T)
+                   for s, sched in zip(specs, per_point)]
+        times = np.stack([t for t, _, _ in stacked])   # (n, n_lanes, R, T)
+        dests = np.stack([d for _, d, _ in stacked])
+        writes = np.stack([w for _, _, w in stacked])
+        sl, mo, bb = _dyn_scalars(base, None, None, None)
+        jt = jitter_table(base)
+        fops = _fault_ops(base)
+        for i in range(len(specs)):
+            _check_dead_traffic(base, times[i], dests[i])
+        depths = np.stack([_depths(s) for s in specs])  # (n, n_ch)
 
-    n = len(specs)
-    n_pad = -(-n // devices) * devices
-    if n_pad != n:
-        reps = n_pad - n
-        pad = functools.partial(np.concatenate, axis=0)
-        times = pad([times, np.repeat(times[-1:], reps, axis=0)])
-        dests = pad([dests, np.repeat(dests[-1:], reps, axis=0)])
-        writes = pad([writes, np.repeat(writes[-1:], reps, axis=0)])
-        depths = pad([depths, np.repeat(depths[-1:], reps, axis=0)])
+        n = len(specs)
+        n_pad = -(-n // devices) * devices
+        if n_pad != n:
+            reps = n_pad - n
+            pad = functools.partial(np.concatenate, axis=0)
+            times = pad([times, np.repeat(times[-1:], reps, axis=0)])
+            dests = pad([dests, np.repeat(dests[-1:], reps, axis=0)])
+            writes = pad([writes, np.repeat(writes[-1:], reps, axis=0)])
+            depths = pad([depths, np.repeat(depths[-1:], reps, axis=0)])
+        args = (times, dests, writes, sl, mo, bb, jt, depths, *fops)
 
-    fn = compiled_farm_sweep(base, T, devices, backend,
-                             max_depth=int(depths.max()))
-    raw = fn(jnp.asarray(times), jnp.asarray(dests), jnp.asarray(writes),
-             jnp.asarray(sl), jnp.asarray(mo), jnp.asarray(bb),
-             jnp.asarray(jt), jnp.asarray(depths),
-             *(jnp.asarray(x) for x in fops))
-    raw = {k: np.asarray(v)[:n] for k, v in raw.items()}
-    return SimResult.from_raw(base, raw)
+    d_max = int(depths.max())
+    prog = compiled_program(
+        ("farm", _depth_normalized(base, d_max)[0], T, devices, backend),
+        lambda: compiled_farm_sweep(base, T, devices, backend,
+                                    max_depth=d_max), args)
+    return run_program(base, prog, args, fold=lambda raw: {
+        k: np.asarray(v)[:n] for k, v in raw.items()})
 
 
 # --------------------------------------------------------------------- #
@@ -269,7 +271,9 @@ def compiled_rowshard_sim(spec: NocSpec, T: int, shard: RowShard,
     """One jitted row-sharded simulator per (depth-normalized spec,
     horizon, shard count), cached in partition ``"rowshard[N]:jnp"``.
     Same operand signature and raw-result keys as
-    :func:`~repro.noc.engine.compiled_sim` (fault-free form)."""
+    :func:`~repro.noc.engine.compiled_sim` (fault-free form); the
+    per-shard leaves it returns take :func:`fold_shards` on the host to
+    the single-device raw shape."""
     _check_rowshard(spec, shard, backend)
     key_spec, d_max = _depth_normalized(spec, None)
     partition = f"rowshard[{shard.n}]:{backend}"
@@ -335,6 +339,7 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
             route=route_l, has_feed=has_feed,
             src_flat=jnp.where(has_feed, ext(srcr_l) * Pn + srco_l, 0))
 
+    @jax.named_scope("farm.halo")
     def _with_halo(x):
         """(R_l, ...) local rows -> (R_ext, ...) with both boundary
         strips exchanged (mesh edges receive ppermute's zero fill,
@@ -403,7 +408,7 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
         # every leaf leaves with a leading gather axis: per-row arrays
         # concatenate back into global row order (shards are contiguous
         # strips); per-shard leaves stack to (n_shards, ...) and are
-        # reduced host-side in run()
+        # reduced host-side by fold_shards()
         return {
             "done": ni.done, "lat_sum": ni.lat_sum,
             "lat_max": ni.lat_max, "beats_rx": ni.beats_rx,
@@ -419,23 +424,17 @@ def _build_rowshard_sim(spec: NocSpec, T: int, n_shards: int, d_max: int):
         }
 
     in_specs = ((P(None, ROW_AXIS),) * 3 + (P(),) * 5)
-    smfn = jax.jit(jax.shard_map(sharded, mesh=mesh, in_specs=in_specs,
+    return jax.jit(jax.shard_map(sharded, mesh=mesh, in_specs=in_specs,
                                  out_specs=P(ROW_AXIS), check_vma=False))
 
-    def run(times, dests, writes, service_lat, max_out, burst_beats,
-            jitter, depths):
-        raw = smfn(jnp.asarray(times), jnp.asarray(dests),
-                   jnp.asarray(writes), jnp.asarray(service_lat),
-                   jnp.asarray(max_out), jnp.asarray(burst_beats),
-                   jnp.asarray(jitter), jnp.asarray(depths, jnp.int32))
-        raw = {k: np.asarray(v) for k, v in raw.items()}
-        # fold the per-shard leaves back to the single-device raw shape
-        raw["link_moves"] = raw["link_moves"].sum(axis=0,
-                                                  dtype=np.int32)
-        raw["max_stall_cycles"] = raw["max_stall_cycles"][0]
-        raw["drained"] = np.bool_(raw["drained"].all())
-        raw["vc_occ_sum"] = raw["vc_occ_sum"][0]
-        raw["vc_occ_max"] = raw["vc_occ_max"][0]
-        return raw
 
-    return run
+def fold_shards(raw) -> dict:
+    """A row-sharded simulator's raw result on the host, its per-shard
+    leaves folded back to the single-device raw shape."""
+    raw = {k: np.asarray(v) for k, v in raw.items()}
+    raw["link_moves"] = raw["link_moves"].sum(axis=0, dtype=np.int32)
+    raw["max_stall_cycles"] = raw["max_stall_cycles"][0]
+    raw["drained"] = np.bool_(raw["drained"].all())
+    raw["vc_occ_sum"] = raw["vc_occ_sum"][0]
+    raw["vc_occ_max"] = raw["vc_occ_max"][0]
+    return raw
