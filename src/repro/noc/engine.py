@@ -54,19 +54,19 @@ exactly.
 The per-cycle structure keeps the fused-hot-loop shape: ONE stacked
 fabric call for all channels, batched ``(R, n_cls)`` NI state (one
 column per (class, AXI ID stream) *lane* — see :class:`FlowPlan`), the
-response rings as one ``(R, n_rq, resp_q_cap, 6)`` array updated with
-a single segment-style scatter per cycle (the per-class W rings live
-in a separate small ``(R, n_cls, w_cap, 6)`` array — W occupancy is
-bounded by the write ROB credit, so it never pays the response-ring
-capacity), and FIFO depth as a traced operand (padded-depth sweeps
-share one compilation).  The engine also watches liveness: ``max_stall_cycles``
-(longest streak with transactions in flight but zero fabric activity)
-and ``drained`` (every scheduled transaction completed) make deadlock
-observable, and per-VC FIFO occupancy (sum + peak per channel) shows
-*where* flits sit when the spec's
-:class:`~repro.noc.routing.RoutingPolicy` runs multiple virtual
-channels — a wedged single-VC torus pins VC0 full while the escape VC
-of a ``n_vcs>=2`` dateline policy keeps draining.
+response rings as one field-major ``(6, n_rq, resp_q_cap, R)`` array
+(the per-class W rings live in a separate small ``(6, n_cls, w_cap,
+R)`` array — W occupancy is bounded by the write ROB credit, so it
+never pays the response-ring capacity) read and pushed with one-hot
+selects over the slot axis, no scatter or gather, and FIFO depth as a
+traced operand (padded-depth sweeps share one compilation).  The
+engine also watches liveness: ``max_stall_cycles`` (longest streak
+with transactions in flight but zero fabric activity) and ``drained``
+(every scheduled transaction completed) make deadlock observable, and
+per-VC FIFO occupancy (sum + peak per channel) shows *where* flits sit
+when the spec's :class:`~repro.noc.routing.RoutingPolicy` runs
+multiple virtual channels — a wedged single-VC torus pins VC0 full
+while the escape VC of a ``n_vcs>=2`` dateline policy keeps draining.
 
 The routing policy is threaded through statically: the backend gets
 ``(spec.topology, spec.routing)`` and runs on the policy's compiled
@@ -202,11 +202,16 @@ class FlowPlan(NamedTuple):
     Ring space: response rings (one per distinct channel carrying any
     R or B flow, first-appearance order) come first, then one W ring
     per lane (id ``n_rq + lane``).  Head/tail/started bookkeeping is
-    one stacked ``(R, n_q)`` set, but the entry storage is split:
-    response rings are ``(R, n_rq, resp_q_cap, 6)`` while W rings are
-    ``(R, n_lanes, w_cap, 6)`` with ``w_cap`` derived from the classes'
-    declared ``max_outstanding`` — a W ring can never hold more
-    pending bursts than the write ROB grants credits, so it doesn't
+    one stacked ``(R, n_q)`` set, with ``head_sent`` beside it: the
+    beats of each head entry already sent, so an entry is written once,
+    at push, and its remaining beats are its pushed ``Q_BEATS`` minus
+    that count.  The entry storage is field-major and split: response
+    rings are ``(6, n_rq, resp_q_cap, R)`` and W rings ``(6, n_lanes,
+    w_cap, R)``.  Routers sit on the minor (lane) axis, so a one-hot
+    select over the slots is a dense elementwise pass and the head read
+    sums across slots, not across lanes.  ``w_cap`` is derived from the
+    classes' declared ``max_outstanding`` — a W ring can never hold
+    more pending bursts than the write ROB grants credits, so it doesn't
     pay the big response-ring capacity (raising ``max_outstanding``
     above the declared value via the traced override can overflow the
     W ring, the same unchecked-overflow contract as ``resp_q_cap``).
@@ -303,8 +308,8 @@ class _PlanArrays(NamedTuple):
     w_kinds: np.ndarray
     b_kinds: np.ndarray
     # response-ring push machinery: slot s in [0, 2*n_lanes) is the R
-    # push of lane s or the B push of lane s-n_lanes; one masked
-    # scatter serves both (W pushes go to the per-lane W-ring array,
+    # push of lane s or the B push of lane s-n_lanes, each one masked
+    # select into its ring (W pushes go to the per-lane W-ring array,
     # where each ring has exactly one pusher — no ordering needed).
     q_of_slot: np.ndarray     # (2*n_lanes,) destination ring per push slot
     push_before: np.ndarray   # (2n, 2n) 1 where slot j pushes the same
@@ -348,8 +353,9 @@ class NIState(NamedTuple):
     out_w: jax.Array        # (R, n_cls) outstanding writes (write ROB)
     rq_head: jax.Array      # (R, n_q) rsp rings first, then W rings
     rq_tail: jax.Array      # (R, n_q)
-    rq: jax.Array           # (R, n_rq, resp_q_cap, 6) response rings
-    wq: jax.Array           # (R, n_cls, w_cap, 6) per-class W rings
+    head_sent: jax.Array    # (R, n_q) beats of the head entry sent
+    rq: jax.Array           # (6, n_rq, resp_q_cap, R) response rings
+    wq: jax.Array           # (6, n_cls, w_cap, R) per-class W rings
     w_started: jax.Array    # (R, n_q) burst mid-stream (inject atomicity)
     inj_rr: jax.Array       # (R, n_ch) mixed-channel round-robin
     # per-class read metrics: (R, n_cls), measured at the requester
@@ -437,15 +443,29 @@ def init_ni(R: int, plan: FlowPlan, cap: int) -> NIState:
     zq = jnp.zeros((R, plan.n_q), jnp.int32)
     big = jnp.full((R, plan.n_cls), BIG, jnp.int32)
     return NIState(
-        ptr=zc, out_r=zc, out_w=zc, rq_head=zq, rq_tail=zq,
-        rq=jnp.zeros((R, plan.n_rq, cap, N_QFIELDS), jnp.int32),
-        wq=jnp.zeros((R, plan.n_cls, plan.w_cap, N_QFIELDS), jnp.int32),
+        ptr=zc, out_r=zc, out_w=zc, rq_head=zq, rq_tail=zq, head_sent=zq,
+        rq=jnp.zeros((N_QFIELDS, plan.n_rq, cap, R), jnp.int32),
+        wq=jnp.zeros((N_QFIELDS, plan.n_cls, plan.w_cap, R), jnp.int32),
         w_started=jnp.zeros((R, plan.n_q), jnp.bool_),
         inj_rr=jnp.zeros((R, plan.n_ch), jnp.int32),
         lat_sum=zc, lat_max=zc, done=zc, beats_rx=zc,
         first_t=big, last_t=zc,
         w_lat_sum=zc, w_lat_max=zc, w_done=zc, w_beats_rx=zc,
         w_first_t=big, w_last_t=zc)
+
+
+def _one_hot(slot: jax.Array, cap: int) -> jax.Array:
+    """``(..., R)`` ring slots as a ``(..., cap, R)`` mask over the slot
+    axis."""
+    return jnp.arange(cap)[:, None] == slot[..., None, :]
+
+
+def _ring_read(ring: jax.Array, head: jax.Array) -> jax.Array:
+    """The entries at ``head`` ``(R, Q)`` of a ``(6, Q, cap, R)`` ring
+    array, ``(6, R, Q)``: a one-hot masked sum over the slot axis (the
+    other slots add zero), one dense pass with no gather."""
+    at = _one_hot(head.T % ring.shape[-2], ring.shape[-2])
+    return jnp.sum(jnp.where(at, ring, 0), axis=-2).transpose(0, 2, 1)
 
 
 def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
@@ -456,9 +476,9 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
     one-cycle fabric update (:class:`repro.noc.backends.Network`).
 
     ``shard`` (row-sharded farm mode, :mod:`repro.noc.farm`) narrows the
-    NI update to that shard's ``local_R`` contiguous router rows: local
-    row indices keep driving the scatters into the shard's own state,
-    while the *global* row id (``local + axis_index * local_R``) is what
+    NI update to that shard's ``local_R`` contiguous router rows: the
+    shard's own NI state holds its local rows only, while the *global*
+    row id (``local + axis_index * local_R``) is what
     enters every flit's src field and the multi-plane hash — those ids
     travel the fabric and come back as response destinations, so they
     must live in the global router id space.  Per-cycle liveness /
@@ -473,7 +493,6 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
     n_vcs = spec.routing.n_vcs
     rows = jnp.arange(R)
     rq_ids = jnp.arange(plan.n_rq)
-    wq_ids = jnp.arange(plan.n_cls)
     n_cls = plan.n_cls
 
     # fault machinery is built ONLY when the spec declares a FaultModel:
@@ -581,17 +600,15 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
             txn_src = jnp.where(has_rt, r_txn, ni.ptr)
 
         # ---- ring heads (response rings + W rings), all at once ---------
-        slot_hr = ni.rq_head[:, :plan.n_rq] % cap      # (R, n_rq)
-        slot_hw = ni.rq_head[:, plan.n_rq:] % w_cap    # (R, n_cls)
         h = jnp.concatenate([
-            jnp.take_along_axis(ni.rq, slot_hr[:, :, None, None],
-                                axis=2)[:, :, 0, :],
-            jnp.take_along_axis(ni.wq, slot_hw[:, :, None, None],
-                                axis=2)[:, :, 0, :]], axis=1)  # (R, n_q, 6)
+            _ring_read(ni.rq, ni.rq_head[:, :plan.n_rq]),
+            _ring_read(ni.wq, ni.rq_head[:, plan.n_rq:])],
+            axis=2)                                    # (6, R, n_q)
         have = ni.rq_head < ni.rq_tail
-        h_ready = have & (h[..., Q_READY] <= now)
-        h_dest, h_beats = h[..., Q_DEST], h[..., Q_BEATS]
-        h_time0, h_txn, h_kind = h[..., Q_TIME0], h[..., Q_TXN], h[..., Q_KIND]
+        h_ready = have & (h[Q_READY] <= now)
+        h_dest, h_time0, h_txn, h_kind = (h[Q_DEST], h[Q_TIME0], h[Q_TXN],
+                                          h[Q_KIND])
+        h_beats = h[Q_BEATS] - ni.head_sent            # beats left to send
         h_held = ni.w_started & (h_beats > 0)          # burst mid-stream
 
         # ---- per-channel injection policy (small static loop) -----------
@@ -799,17 +816,16 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
             inj = (inj_ar | inj_aw).astype(jnp.int32)
             cr_ar = inj_ar.astype(jnp.int32)
             cr_aw = inj_aw.astype(jnp.int32)
+        # the ring keeps each entry as pushed: a sent beat only counts
+        # in head_sent, which restarts at 0 when the head advances
         left = h_beats - sent.astype(jnp.int32)
-        beats_upd = jnp.where(sent, left, h_beats)     # (R, n_q)
-        rq = ni.rq.at[rows[:, None], rq_ids[None, :], slot_hr,
-                      Q_BEATS].set(beats_upd[:, :plan.n_rq])
-        wq = ni.wq.at[rows[:, None], wq_ids[None, :], slot_hw,
-                      Q_BEATS].set(beats_upd[:, plan.n_rq:])
         ni = ni._replace(
             ptr=ni.ptr + inj, out_r=ni.out_r + cr_ar,
             out_w=ni.out_w + cr_aw, inj_rr=inj_rr,
-            rq=rq, wq=wq,
             rq_head=ni.rq_head + (sent & (left <= 0)).astype(jnp.int32),
+            head_sent=jnp.where(sent, jnp.where(left > 0,
+                                                ni.head_sent + 1, 0),
+                                ni.head_sent),
             w_started=jnp.where(sent, left > 0, ni.w_started))
 
         if faulted:
@@ -847,11 +863,13 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
         is_b, df_b = flow_dv(pa.b_ch, pa.b_kinds)
         is_w_last = is_w & (df_w[..., F_BEAT] <= 1)
 
-        # ---- ring pushes: ONE response-ring scatter + one W scatter -----
+        # ---- ring pushes: one masked select per push slot ---------------
         # response slot layout: [R pushes | B pushes] per class; the
         # slot's ring slot = its ring's tail + #earlier same-ring
-        # pushes.  W pushes land in the per-class W-ring array, where
-        # each ring has exactly one pusher per cycle (its own AW grant)
+        # pushes, so same-cycle pushes into one ring land on distinct
+        # slots and the selects commute.  W pushes land in the
+        # per-class W-ring array, where each ring has exactly one
+        # pusher per cycle (its own AW grant): one select for all
         sl = service_lat[None, :].astype(jnp.int32)
         jt = jnp.asarray(jitter, jnp.int32)
 
@@ -874,32 +892,36 @@ def make_step(spec: NocSpec, plan: FlowPlan, T: int, net_step,
             df_ar[..., F_SRC], bb, df_ar[..., F_TIME],
             df_ar[..., F_TXN],
             jnp.broadcast_to(pa.r_kinds[None, :], (R, n_cls)),
-        ], axis=-1)
+        ])                                             # (6, R, n_cls)
         push_b = jnp.stack([
             now + sl + jit_of(df_w[..., F_TXN], df_w[..., F_SRC]),
             df_w[..., F_SRC], jnp.ones((R, n_cls), jnp.int32),
             df_w[..., F_TIME], df_w[..., F_TXN],
             jnp.broadcast_to(pa.b_kinds[None, :], (R, n_cls)),
-        ], axis=-1)
+        ])
         push_w = jnp.stack([
             jnp.broadcast_to(now + 1, (R, n_cls)), req_d, bb,
             jnp.broadcast_to(now, (R, n_cls)), txn0,
             jnp.broadcast_to(pa.w_kinds[None, :], (R, n_cls)),
-        ], axis=-1)
+        ]).astype(jnp.int32)
         active = jnp.concatenate([is_ar, is_w_last], axis=1)
         push_val = jnp.concatenate([push_r, push_b],
-                                   axis=1).astype(jnp.int32)
+                                   axis=2).astype(jnp.int32)
         offset = jnp.einsum("rj,ij->ri", active.astype(jnp.int32),
                             jnp.asarray(pa.push_before))
         tail_of_slot = ni.rq_tail[:, pa.q_of_slot]     # (R, 2*n_cls)
         slot_p = (tail_of_slot + offset) % cap
-        slot_p = jnp.where(active, slot_p, cap)  # masked -> OOB, dropped
-        rq = ni.rq.at[rows[:, None], pa.q_of_slot[None, :],
-                      slot_p].set(push_val, mode="drop")
-        tail_w = ni.rq_tail[:, plan.n_rq:]             # (R, n_cls)
-        slot_pw = jnp.where(inj_aw, tail_w % w_cap, w_cap)
-        wq = ni.wq.at[rows[:, None], wq_ids[None, :],
-                      slot_pw].set(push_w.astype(jnp.int32), mode="drop")
+        # slot-major push operands: each slot's select reads whole rows
+        # (strided column slices cost XLA's CPU backend ~2.5x per call)
+        vals, slots, on = jnp.moveaxis(push_val, 2, 0), slot_p.T, active.T
+        rq = ni.rq
+        for s, q in enumerate(pa.q_of_slot):
+            at = _one_hot(slots[s], cap) & on[s]         # (cap, R)
+            at = at & (rq_ids == q)[:, None, None]       # (n_rq, cap, R)
+            rq = jnp.where(at, vals[s][:, None, None, :], rq)
+        tail_w = ni.rq_tail[:, plan.n_rq:].T           # (n_cls, R)
+        at_w = _one_hot(tail_w % w_cap, w_cap) & inj_aw.T[:, None, :]
+        wq = jnp.where(at_w, jnp.moveaxis(push_w, 1, 2)[:, :, None], ni.wq)
         tail_inc = jnp.concatenate(
             [active.astype(jnp.int32) @ pa.q_onehot,
              inj_aw.astype(jnp.int32)], axis=1)        # (R, n_q)

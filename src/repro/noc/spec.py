@@ -141,7 +141,9 @@ class NocSpec:
     service_lat: int = 10          # target memory + NI latency (cycles)
     cycles: int = 4000
     # per-NI response reorder-ring capacity (entries per queue).  Sizes
-    # the engine's (R, n_rq, resp_q_cap, 6) ring state, so small
+    # the engine's field-major (6, n_rq, resp_q_cap, R) ring state (an
+    # entry is written once, at push; an (R, n_q) counter beside the
+    # head/tail pointers tracks the head's sent beats), so small
     # studies can shrink it; must cover the worst-case R+B responses
     # pending at one NI (bounded by sum over classes of max_outstanding
     # x #sources targeting it — the engine does not check overflow at

@@ -13,7 +13,9 @@ loads the TPU library.
 from __future__ import annotations
 
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +29,7 @@ from repro.noc import (FaultModel, NocSpec, RoutingPolicy, Workload,
                        sim_cache_clear)
 from repro.noc.api import _depths, _dyn_scalars, jitter_table, stack_schedules
 from repro.noc.backends import _resolve_tables, get_backend
-from repro.noc.engine import compiled_sim
+from repro.noc.engine import N_QFIELDS, build_flow_plan, compiled_sim
 
 
 @pytest.fixture(scope="module")
@@ -131,11 +133,9 @@ def test_arbiter_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
-def test_simulator_compiles(one_chip, mosaic, backend):
-    """The whole jitted simulator of the 7x7 smoke spec — the program
-    ``chip_smoke.py`` runs — compiles for one v5e chip."""
-    spec = _spec(7)
+def _simulator_hlo(one_chip, spec: NocSpec, backend: str) -> str:
+    """The compiled HLO text of the jitted simulator of ``spec`` under
+    the smoke workload, for one v5e chip."""
     times, dests, writes = stack_schedules(
         spec, _smoke_workload().schedules(spec))
     sl, mo, bb = _dyn_scalars(spec, None, None, None)
@@ -144,6 +144,43 @@ def test_simulator_compiles(one_chip, mosaic, backend):
     fn = compiled_sim(spec, times.shape[-1], backend)
     args = [_shape(one_chip, np.shape(x), np.asarray(x).dtype)
             for x in operands]
-    compiled = fn.lower(*args).compile()
-    text = compiled.as_text()
+    return fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas_fused"])
+def test_simulator_compiles(one_chip, mosaic, backend):
+    """The whole jitted simulator of the 7x7 smoke spec — the program
+    ``chip_smoke.py`` runs — compiles for one v5e chip."""
+    text = _simulator_hlo(one_chip, _spec(7), backend)
     assert ("tpu_custom_call" in text) == (backend == "pallas_fused")
+
+
+def _indexing(text: str) -> list[tuple[str, str, list[int]]]:
+    """``(op, instruction, operand dims)`` for every operand of every
+    gather and scatter in compiled HLO ``text``, each operand's shape
+    looked up from the instruction that defines it."""
+    shape_of = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    out = []
+    for name, op, operands in re.findall(
+            r"%([\w.\-]+) = .*?\b(gather|scatter)\(([^)]*)\)", text):
+        for operand in re.findall(r"%([\w.\-]+)", operands):
+            dims = shape_of.get(operand, "")
+            out.append((op, name, [int(d) for d in dims.split(",") if d]))
+    return out
+
+
+def test_simulator_indexes_no_ring(one_chip, mosaic):
+    """The NI rings are read and pushed with one-hot selects: the
+    compiled 7x7 ``jnp`` simulator has no gather or scatter on an
+    operand with a ring's slot extent, or a ring's element count (a
+    ring flattened by the compiler)."""
+    spec = _spec(7)
+    plan = build_flow_plan(spec)
+    extents = {spec.resp_q_cap, plan.w_cap}
+    sizes = {N_QFIELDS * spec.n_routers * plan.n_rq * spec.resp_q_cap,
+             N_QFIELDS * spec.n_routers * plan.n_cls * plan.w_cap}
+    found = _indexing(_simulator_hlo(one_chip, spec, "jnp"))
+    assert found                    # the schedule lookups are gathers
+    ring = [f for f in found
+            if set(f[2]) & extents or math.prod(f[2]) in sizes]
+    assert ring == []
