@@ -53,6 +53,7 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     root: Path
+    config_file: str = ""
     readers: dict = field(default_factory=dict)
 
     def reader(self, metric: str):
@@ -80,7 +81,9 @@ def _applies(metric: dict, cell: str) -> bool:
 def resolve(name: str, root: Path = ROOT) -> Cell:
     """The cell called ``name`` with its configuration, mix, layout and
     the metrics it reports; raises ``KeyError``/``FileNotFoundError``
-    for a name or file that does not exist."""
+    for a name or file that does not exist, and ``ValueError`` (naming
+    the file) for a configuration that states a fabric the harness
+    cannot build (:func:`stated`)."""
     man = load_manifest(root)
     cells = {w["name"]: w for w in man["workloads"]}
     if name not in cells:
@@ -89,6 +92,10 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     cfg_entry = {c["name"]: c for c in man["configs"]}[w["config"]]
     config = json.loads((root / cfg_entry["file"]).read_text())
+    try:
+        stated(config)
+    except ValueError as e:
+        raise ValueError(f"{cfg_entry['file']}: {e}") from None
     mix = json.loads((root / "bench" / "traffic" / w["config"]
                       / f"{w['traffic']}.json").read_text())
     layout = config["layout"][str(w["chips"])]
@@ -96,7 +103,7 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
                 layout=layout,
                 end_to_end=[m for m in man["end_to_end"] if _applies(m, name)],
                 per_layer=[m for m in man["per_layer"] if _applies(m, name)],
-                root=root)
+                root=root, config_file=cfg_entry["file"])
 
 
 # --------------------------------------------------------------------- #
@@ -115,29 +122,104 @@ def _point_config(config: dict, spec_kw: dict) -> dict:
     return cfg
 
 
+# what a configuration file may state about its fabric, and the value
+# of each key a file leaves out
+TOPOLOGY_DEFAULTS = {"express": [], "routing": "xy", "n_vcs": 1}
+CLASS_DEFAULTS = {"service_jitter": 0, "n_streams": 1}
+KINDS = ("mesh", "torus")
+# routing algorithm -> its route planes; ``valiant`` has ``n_valiant``
+PLANES = {"xy": 1, "o1turn": 2}
+ALGORITHMS = (*PLANES, "valiant")
+VALIANT_PLANES = 2
+TOPOLOGY_KEYS = {"kind", "nx", "ny", "n_valiant", *TOPOLOGY_DEFAULTS}
+CLASS_KEYS = {"name", "burst_beats", "max_outstanding", "payload_bits",
+              *CLASS_DEFAULTS}
+
+
+def stated(cfg: dict) -> dict:
+    """The fabric a configuration states, every default filled in:
+    ``kind``, ``express`` (sorted), ``routing`` as ``(algorithm, n_vcs,
+    n_planes)``, ``n_valiant``, and ``classes`` as ``{name:
+    {service_jitter, n_streams}}``.  Raises ``ValueError`` for a kind,
+    algorithm or key the harness cannot build, and for a fault model,
+    which it never builds, so that no file is built as a fabric other
+    than the one it states."""
+    topo = cfg["topology"]
+    unknown = set(topo) - TOPOLOGY_KEYS
+    unknown |= {k for c in cfg["classes"] for k in c if k not in CLASS_KEYS}
+    if unknown:
+        raise ValueError(f"keys the harness cannot build: {sorted(unknown)}")
+    if "faults" in cfg:
+        raise ValueError("fault configurations are not built by the "
+                         "harness")
+    if topo.get("kind") not in KINDS:
+        raise ValueError(f"topology kind {topo.get('kind')!r}; the harness "
+                         f"builds {list(KINDS)}")
+    top = {**TOPOLOGY_DEFAULTS, **topo}
+    algorithm = top["routing"]
+    if algorithm not in ALGORITHMS:
+        raise ValueError(f"routing {algorithm!r}; the harness builds "
+                         f"{list(ALGORITHMS)}")
+    if "n_valiant" in topo and algorithm != "valiant":
+        raise ValueError("n_valiant is stated only with routing 'valiant'")
+    n_valiant = topo.get("n_valiant", VALIANT_PLANES)
+    planes = n_valiant if algorithm == "valiant" else PLANES[algorithm]
+    return {"kind": top["kind"], "express": sorted(top["express"]),
+            "routing": (algorithm, top["n_vcs"], planes),
+            "n_valiant": n_valiant,
+            "classes": {c["name"]: {k: c.get(k, v)
+                                    for k, v in CLASS_DEFAULTS.items()}
+                        for c in cfg["classes"]}}
+
+
 def build_spec(cfg: dict):
-    from repro.noc import NocSpec
-    return getattr(NocSpec, cfg["preset"])(**cfg["preset_args"])
+    """The configuration's preset called with its ``preset_args`` and
+    the topology and routing the file states, each class given the
+    streams and service jitter it states."""
+    from dataclasses import replace
+    from repro.noc import Mesh, NocSpec, RoutingPolicy, Torus
+    fab = stated(cfg)
+    args = cfg["preset_args"]
+    topology = (Torus(args["nx"], args["ny"]) if fab["kind"] == "torus"
+                else Mesh(args["nx"], args["ny"],
+                          express=tuple(fab["express"])))
+    algorithm, n_vcs, _ = fab["routing"]
+    spec = getattr(NocSpec, cfg["preset"])(
+        **args, topology=topology,
+        routing=RoutingPolicy(algorithm, n_vcs, fab["n_valiant"]))
+    return spec.with_(classes=tuple(
+        replace(c, **fab["classes"][c.name]) if c.name in fab["classes"]
+        else c for c in spec.classes))
 
 
 def spec_mismatches(spec, cfg: dict) -> int:
     """How many of the facts the configuration states the program's
-    spec departs from (topology, horizon, networks, classes, flow map,
-    service latency, queue capacity, routing)."""
-    stated = {
+    spec departs from (topology and its kind and express links,
+    horizon, networks, classes with their jitter and streams, flow map,
+    service latency, queue capacity, routing algorithm with its VCs and
+    planes); a fault model always departs."""
+    fab = stated(cfg)
+    stated_facts = {
         "topology": (cfg["topology"]["nx"], cfg["topology"]["ny"]),
+        "kind": fab["kind"],
+        "express": fab["express"],
         "cycles": cfg["cycles"],
         "service_lat": cfg["service_lat"],
         "resp_q_cap": cfg["resp_q_cap"],
         "channels": [(c["name"], c["depth"], c["width_bits"])
                      for c in cfg["channels"]],
         "classes": [(c["name"], c["burst_beats"], c["max_outstanding"],
-                     c["payload_bits"], 0, 1) for c in cfg["classes"]],
+                     c["payload_bits"],
+                     fab["classes"][c["name"]]["service_jitter"],
+                     fab["classes"][c["name"]]["n_streams"])
+                    for c in cfg["classes"]],
         "class_map": sorted(cfg["class_map"].items()),
-        "routing": (1, 1),
+        "routing": fab["routing"],
     }
     got = {
         "topology": (spec.topology.nx, spec.topology.ny),
+        "kind": type(spec.topology).__name__.lower(),
+        "express": sorted(getattr(spec.topology, "express", ())),
         "cycles": spec.cycles,
         "service_lat": spec.service_lat,
         "resp_q_cap": spec.resp_q_cap,
@@ -146,11 +228,10 @@ def spec_mismatches(spec, cfg: dict) -> int:
                      c.payload_bits, c.service_jitter, c.n_streams)
                     for c in spec.classes],
         "class_map": sorted(spec.class_map),
-        "routing": (spec.routing.n_vcs, spec.routing.n_planes),
+        "routing": (spec.routing.algorithm, spec.routing.n_vcs,
+                    spec.routing.n_planes),
     }
-    bad = sum(stated[k] != got[k] for k in stated)
-    bad += type(spec.topology).__name__ != "Mesh" or bool(
-        getattr(spec.topology, "express", ()))
+    bad = sum(stated_facts[k] != got[k] for k in stated_facts)
     bad += spec.faults is not None
     bad += any(c.service_lat not in (None, cfg["service_lat"])
                for c in spec.classes)
@@ -167,7 +248,10 @@ class System:
         self.points = traffic.point_params(cell.mix)
         self.configs = [_point_config(cell.config, kw)
                         for kw, _ in self.points]
-        self.specs = [build_spec(c) for c in self.configs]
+        try:
+            self.specs = [build_spec(c) for c in self.configs]
+        except (TypeError, ValueError) as e:
+            raise ValueError(f"{cell.config_file}: {e}") from e
         self.mismatches = sum(spec_mismatches(s, c)
                               for s, c in zip(self.specs, self.configs))
         rows = cell.layout.get("shard_rows")
@@ -304,12 +388,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_start: float, device_info: dict) -> dict:
     """Set up, warm up, measure for ``seconds``, check against the
     reference; returns the result line as a dict."""
-    from repro.noc import sim_cache_stats
+    from repro.noc import sim_cache_stats, span_stats
 
     compiles = _count_compiles()
     system = System(cell, seed)
     system.call(None)                       # the cell's shapes, compiled
     setup_s = time.perf_counter() - t_start
+    setup_spans = span_stats()
     misses0, n_compiles0 = sim_cache_stats()["misses"], len(compiles)
 
     calls, results, failed, undrained = [], [], 0, 0
@@ -341,7 +426,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         f"{len(pauses)}, {sum(t for _, t in pauses):.4f} s in all, longest "
         f"{max((t for _, t in pauses), default=0.0):.4f} s")
 
-    run = {"setup_s": setup_s, "calls": calls, "cell": cell.name,
+    run = {"setup_s": setup_s, "setup_spans": setup_spans,
+           "calls": calls, "cell": cell.name,
            "points": len(system.specs), "cycles": system.specs[0].cycles,
            "chips": cell.chips, "trace": None}
     if trace:
@@ -354,7 +440,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 f"loop iterations, {tr['ops_per_iteration']:.1f} ops and "
                 f"{1e6 * tr['collective_s_per_iteration']:.2f} us of "
                 f"collectives per iteration, reduced in "
-                f"{tr['reduce_s']:.1f} s")
+                f"{tr['reduce_s']:.1f} s after {tr['hlo_s']:.1f} s "
+                f"lowering the programs' HLO")
     device = dict(device_info)
     device["memory_peak_bytes"] = peak_memory(cell.chips)
 

@@ -1,38 +1,46 @@
-"""The device loop split by the program's phase scopes, and the host
-gap between two calls split by the program's own spans.
+"""The device loop split by the program's scopes, and the host gap
+between two calls split by the program's own spans.
 
 The program runs every operation of its scan step under one of four
-outermost ``jax.named_scope``s (:data:`PHASES`) and marks each API
-call's host work with ``jax.profiler.TraceAnnotation`` spans that carry
-``call=<id>`` (``noc.verify`` ... ``noc.results``, see
-``repro.noc.span_stats``).  Both land in the same profiler trace as the
-device ops, so this module splits what ``bench/tracing.py`` measures:
+outermost ``jax.named_scope``s (:data:`PHASES`), possibly with further
+``noc.*`` scopes nested inside, and marks each API call's host work
+with ``jax.profiler.TraceAnnotation`` spans that carry ``call=<id>``
+(``noc.verify`` ... ``noc.results``, see ``repro.noc.span_stats``).
+Both land in the same profiler trace as the device ops, so this module
+splits what ``bench/tracing.py`` measures:
 
-* busy time per iteration of the device loop, by the phase of each
-  op's op-name metadata (a fusion carries its root's), with the ops
-  that carry no phase as the unscoped rest;
+* busy time per iteration of the device loop, by the outermost phase
+  of each op's op-name metadata (a fusion carries its root's), with
+  the ops that carry no phase as the unscoped rest; and by every
+  ``noc.*`` scope, a nested one counting in its own entry and in each
+  enclosing scope's, so a scope the program adds is a key, not code;
 * the boundary between two device programs, by the innermost program
   span open at each instant: the finishing call's ``noc.results``, the
   next call's ``noc.verify``/``noc.schedules``/``noc.build``/
-  ``noc.dispatch``, and what no program span covers.
+  ``noc.dispatch``, and what no program span covers; and by the time
+  each span name covers.
 
 Planes are those of ``bench/tracing.py`` with an optional fourth
-element per device event, its phase (``None`` for none), and host
-events ``(name, start_ns, end_ns, stats)``; 3-tuples reduce as
-unscoped ops and spans without a call id.
+element per device event, its scope path (``noc.fabric/noc.vc_arb``,
+outermost first; ``None`` for none), and host events ``(name,
+start_ns, end_ns, stats)``; 3-tuples reduce as unscoped ops and spans
+without a call id.  The benchmark's traced run reads a trace with
+:func:`load` and merges both splits into its reduction
+(``bench/tracing.py``).
 
 ``python bench/phases.py --workload <cell> --seed <n>`` runs a cell's
 warm call, a few timed calls and one traced boundary on the chip, and
 prints both splits beside the existing reduction's numbers; ``--out
-<dir>`` keeps the whole output as ``<dir>/<cell>.json``.  The
-benchmark's own runs do not read this module.
+<dir>`` keeps the whole output as ``<dir>/<cell>.json``.
 """
 from __future__ import annotations
 
 import collections
+import functools
 import re
 import sys
 from pathlib import Path
+from typing import Callable
 
 if __package__ in (None, ""):                  # run as a script
     sys.path[:0] = [str(Path(__file__).resolve().parents[1])]
@@ -44,28 +52,45 @@ from bench import tracing  # noqa: E402
 PHASES = ("noc.ni_issue", "noc.fabric", "noc.ni_rings", "noc.stats")
 PREPARE = ("noc.verify", "noc.schedules", "noc.build", "noc.dispatch")
 RESULTS = ("noc.results",)
-SPAN_PREFIX = "noc."
+SPAN_PREFIX = "noc."              # of the program's spans and scopes
 # event stats that may hold an op's op-name metadata on a device plane
 OP_NAME_STATS = ("tf_op", "op_name", "long_name", "name")
 NO_SPAN = "no program span"
 
 
+@functools.lru_cache(maxsize=4096)
 def phase_of(op_name: str | None) -> str | None:
     """The outermost phase scope in an op name such as
     ``jit(run)/while/body/closed_call/noc.fabric/fabric_front/gather``
-    (``noc.fabric``), or ``None`` where it holds none."""
+    (``noc.fabric``) or in a scope path, or ``None`` where it holds
+    none."""
     for part in (op_name or "").split("/"):
         if part in PHASES:
             return part
     return None
 
 
-def phase_of_stats(stats: dict) -> str | None:
-    """The phase named by an event's op-name stats, if any."""
+@functools.lru_cache(maxsize=4096)
+def scope_names(op_name: str | None) -> tuple[str, ...]:
+    """Every ``noc.*`` scope in an op name or scope path, outermost
+    first, each once."""
+    return tuple(dict.fromkeys(p for p in (op_name or "").split("/")
+                               if p.startswith(SPAN_PREFIX)))
+
+
+def scopes_of(op_name: str | None) -> str | None:
+    """An op name's scope path:
+    ``jit(run)/while/body/noc.fabric/fabric_update/noc.vc_arb/select``
+    -> ``noc.fabric/noc.vc_arb``; ``None`` where it holds none."""
+    return "/".join(scope_names(op_name)) or None
+
+
+def scopes_of_stats(stats: dict) -> str | None:
+    """The scope path of an event's op-name stats, if any."""
     for k in OP_NAME_STATS:
         v = stats.get(k)
-        if isinstance(v, str) and phase_of(v) is not None:
-            return phase_of(v)
+        if isinstance(v, str) and scopes_of(v) is not None:
+            return scopes_of(v)
     return None
 
 
@@ -75,12 +100,12 @@ _CALLS = re.compile(r"calls=%?([\w.-]+)")
 _COMP = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.-]+)\s.*\{\s*$")
 
 
-def hlo_phases(hlo_text: str) -> dict[str, str]:
-    """``{instruction: phase}`` from a compiled module's HLO text: each
-    instruction's own op-name metadata or, for a fusion (any call)
+def hlo_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction: scope path}`` from a compiled module's HLO text:
+    each instruction's own op-name metadata or, for a fusion (any call)
     without one, its called computation's root's — and where the
     compiler left the root without metadata (a rewritten scatter), the
-    phase of the computation's last instruction that has one."""
+    scopes of the computation's last instruction that has any."""
     own, calls, roots, last, comp = {}, {}, {}, {}, None
     for line in hlo_text.splitlines():
         m = _INSTR.match(line)
@@ -90,7 +115,7 @@ def hlo_phases(hlo_text: str) -> dict[str, str]:
             continue
         name = m.group(2)
         op = _OP_NAME.search(line)
-        own[name] = phase_of(op.group(1)) if op else None
+        own[name] = scopes_of(op.group(1)) if op else None
         if own[name] is not None and comp is not None:
             last[comp] = own[name]
         called = _CALLS.search(line)
@@ -117,19 +142,23 @@ def op_key(event_name: str) -> str:
     return tracing.short_name(event_name).split(" ")[0].lstrip("%")
 
 
-def _phase(ev) -> str | None:
+def _scopes(ev) -> str | None:
     return ev[3] if len(ev) > 3 else None
 
 
 def reduce_phases(planes, points: int, top: int = 10) -> dict | None:
-    """Busy seconds per loop iteration by phase, mean over devices.
+    """Busy seconds per loop iteration by phase and by scope, mean over
+    devices.
 
     Loop ops, iterations and the boundary are found as
     :func:`bench.tracing.reduce` finds them; each phase is the union of
     its loop ops' intervals on the ``XLA Ops`` line, the rest that of
     the loop ops with no phase, so on a line whose ops do not overlap
     the phases and the rest add up to ``busy_s_per_iteration``.
-    ``None`` where the trace holds no device loop."""
+    ``scope_s_per_iteration`` has one entry for every ``noc.*`` scope
+    found: the union of the loop ops under it, nested scopes included
+    (a ``noc.fabric/noc.vc_arb`` op counts in both entries).  ``None``
+    where the trace holds no device loop."""
     devs = [ev for _, ev, _ in tracing.device_lines(planes) if ev]
     if not devs:
         return None
@@ -142,23 +171,29 @@ def reduce_phases(planes, points: int, top: int = 10) -> dict | None:
         loop = [e for e in events if names[e[0]] >= 3]
         busy, _ = tracing.union_length([(e[1], e[2]) for e in loop])
         by = {p: [] for p in PHASES + (None,)}
+        scopes = collections.defaultdict(list)
         for e in loop:
-            by[_phase(e)].append((e[1], e[2]))
-            label = _phase(e) or "unscoped"
-            ops[f"{label} {tracing.short_name(e[0])}"] += \
+            phase = phase_of(_scopes(e))
+            by[phase].append((e[1], e[2]))
+            for sc in scope_names(_scopes(e)):
+                scopes[sc].append((e[1], e[2]))
+            ops[f"{phase or 'unscoped'} {tracing.short_name(e[0])}"] += \
                 (e[2] - e[1]) / iters / len(devs)
-            if _phase(e) is None:
+            if phase is None:
                 rest_ops[tracing.short_name(e[0])] += \
                     (e[2] - e[1]) / iters / len(devs)
         per.append({"busy": busy / iters,
                     **{p: tracing.union_length(v)[0] / iters
-                       for p, v in by.items()}})
+                       for p, v in by.items()},
+                    "scopes": {sc: tracing.union_length(v)[0] / iters
+                               for sc, v in scopes.items()}})
     n = len(per)
 
     def mean(k):
         return sum(p[k] for p in per) / n / 1e9
 
     scoped = any(p[k] for p in per for k in PHASES)
+    found = dict.fromkeys(sc for p in per for sc in p["scopes"])
     return {
         "devices": n,
         "points": points,
@@ -168,6 +203,9 @@ def reduce_phases(planes, points: int, top: int = 10) -> dict | None:
         "phase_s_per_iteration": ({p: mean(p) for p in PHASES}
                                   if scoped else None),
         "unscoped_s_per_iteration": mean(None),
+        "scope_s_per_iteration": {
+            sc: sum(p["scopes"].get(sc, 0) for p in per) / n / 1e9
+            for sc in found},
         "unscoped_ops": [[k, v / 1e9] for k, v in rest_ops.most_common(top)],
         "device_ops": [[k, v / 1e9] for k, v in ops.most_common(top)],
         # every loop op: its phase and seconds per iteration
@@ -251,15 +289,21 @@ def boundary_split(bound, spans) -> dict:
             prepare += b - a
         elif role == "finishing" and sp[0] in RESULTS:
             results += b - a
+    by_span = collections.defaultdict(list)
+    for name, s, e, _ in spans:
+        by_span[name].append((max(s, b0), min(e, b1)))
     return {"parts": dict(parts), "prepare": prepare, "results": results,
-            "spans": len(spans)}
+            "spans": len(spans),
+            "by_span": {k: tracing.union_length(v)[0]
+                        for k, v in by_span.items()}}
 
 
 def reduce_host(planes) -> dict | None:
     """The boundary of each device's loop (as
-    :func:`bench.tracing.reduce` finds it) split by program spans, mean
-    over devices; ``None`` where the window holds no boundary or no
-    program span covers any of it."""
+    :func:`bench.tracing.reduce` finds it) split by program spans, and
+    the time of it each span name covers, mean over devices; ``None``
+    where the window holds no boundary or no program span covers any
+    of it."""
     devs = [ev for _, ev, _ in tracing.device_lines(planes) if ev]
     splits = []
     for events in devs:
@@ -283,10 +327,12 @@ def reduce_host(planes) -> dict | None:
     if not splits or not all(s["spans"] for s in splits):
         return None
     n = len(splits)
-    parts = collections.Counter()
+    parts, by_span = collections.Counter(), collections.Counter()
     for s in splits:
         for k, v in s["parts"].items():
             parts[k] += v / n / 1e9
+        for k, v in s["by_span"].items():
+            by_span[k] += v / n / 1e9
     return {
         "host_s_per_experiment": sum(s["boundary"] for s in splits) / n / 1e9,
         "host_prepare_s_per_experiment":
@@ -296,22 +342,26 @@ def reduce_host(planes) -> dict | None:
         "device_busy_s_in_boundary": sum(s["device"] for s in splits) / n
         / 1e9,
         "host_parts": [[k, v] for k, v in parts.most_common()],
+        # the boundary's time under each span name, a nested span's in
+        # its own entry and in each enclosing span's
+        "span_s_per_experiment": dict(by_span),
     }
 
 
 # --------------------------------------------------------------------- #
 # reading a trace file, and the traced run on the chip
 # --------------------------------------------------------------------- #
-def load(path: Path, hlo: dict[str, str] | None = None,
+def load(path: Path, hlo: Callable[[], dict[str, str]] | None = None,
          sample: int = 0) -> tuple[list, list]:
-    """The planes of an ``.xplane.pb`` with each device event's phase
-    (from its op-name stats where the trace has them — the TPU's has
-    not — else ``hlo``'s instruction map) and each host event's stats;
-    and the stats of up to ``sample`` distinct device ops, to read by
-    hand."""
+    """The planes of an ``.xplane.pb`` with each device event's scope
+    path (from its op-name stats where the trace has them — the TPU's
+    has not — else from the instruction map ``hlo()`` returns, called
+    once at the first op that needs it, so a trace without device ops
+    lowers nothing) and each host event's stats; and the stats of up to
+    ``sample`` distinct device ops, to read by hand."""
     import jax
     pd = jax.profiler.ProfileData.from_file(str(path))
-    planes, seen, phase = [], {}, {}     # phase: per op, read once
+    planes, seen, scope, table = [], {}, {}, None   # scope: per op, once
     for p in pd.planes:
         device = p.name.startswith("/device:")
         lines = []
@@ -320,14 +370,16 @@ def load(path: Path, hlo: dict[str, str] | None = None,
             for e in ln.events:
                 end = e.start_ns + e.duration_ns
                 if device:
-                    if e.name not in phase:
+                    if e.name not in scope:
                         stats = {k: v for k, v in e.stats}
-                        phase[e.name] = phase_of_stats(stats) or (
-                            hlo or {}).get(op_key(e.name))
+                        scope[e.name] = scopes_of_stats(stats)
+                        if scope[e.name] is None and hlo is not None:
+                            table = hlo() if table is None else table
+                            scope[e.name] = table.get(op_key(e.name))
                         if len(seen) < sample:
                             seen[e.name] = {k: str(v)[:300]
                                             for k, v in stats.items()}
-                    events.append((e.name, e.start_ns, end, phase[e.name]))
+                    events.append((e.name, e.start_ns, end, scope[e.name]))
                 elif e.name.startswith(SPAN_PREFIX):
                     events.append((e.name, e.start_ns, end,
                                    {k: v for k, v in e.stats}))
@@ -349,15 +401,15 @@ def plain(planes) -> list:
 
 
 def compiled_hlo() -> dict[str, str]:
-    """``{instruction: phase}`` over the programs the process compiled
-    (a cell runs one), for a trace whose device events carry no op
-    names."""
+    """``{instruction: scope path}`` over the programs the process
+    compiled (a cell runs one), each lowered once, for a trace whose
+    device events carry no op names."""
     import jax
     from repro.noc import engine
     out = {}
     for (_, _, avals), prog in list(engine._programs.items()):
         shapes = [jax.ShapeDtypeStruct(s, d) for s, d in avals]
-        out.update(hlo_phases(prog.lower(*shapes).compile().as_text()))
+        out.update(hlo_scopes(prog.lower(*shapes).compile().as_text()))
     return out
 
 
@@ -383,9 +435,7 @@ def measure(cell, seed: int, calls: int, trace_root: Path) -> dict:
     deleted, until reduced): both splits, the setup spans, the timed
     calls' spans and the traced calls' times."""
     import shutil
-    import threading
     import time
-    import jax
     from bench import harness
     from repro.noc import span_stats, span_stats_clear
 
@@ -408,32 +458,10 @@ def measure(cell, seed: int, calls: int, trace_root: Path) -> dict:
     res["us_per_span"] = span_cost()
 
     trace_dir = trace_root / f"phases_{cell.name}"
-    traced = []
-
-    def work():
-        for experiment in (10 ** 6, 10 ** 6 + 1):
-            a = time.perf_counter()
-            with jax.profiler.TraceAnnotation(tracing.START):
-                pass
-            with jax.profiler.TraceAnnotation(tracing.SPAN):
-                system.call(experiment)
-            with jax.profiler.TraceAnnotation(tracing.END):
-                pass
-            traced.append(time.perf_counter() - a)
-
-    expected = sum(secs) / len(secs)
-    th = threading.Thread(target=work)
-    th.start()
-    time.sleep(max(0.0, expected - tracing.LEAD_SECONDS))
-    jax.profiler.start_trace(str(trace_dir))
-    try:
-        time.sleep(tracing.TRACE_SECONDS)
-    finally:
-        jax.profiler.stop_trace()
-    th.join()
-    res["traced_call_s"] = traced
+    res["traced_call_s"] = tracing.capture(system, trace_dir,
+                                           sum(secs) / len(secs))
     files = sorted(trace_dir.glob("**/*.xplane.pb"))
-    planes, res["op_stats_sample"] = load(files[0], hlo=compiled_hlo(),
+    planes, res["op_stats_sample"] = load(files[0], hlo=compiled_hlo,
                                           sample=12)
     res["tracing"] = tracing.reduce(plain(planes), len(system.specs))
     res["phases"] = reduce_phases(planes, len(system.specs))

@@ -125,8 +125,14 @@ def simulate(cfg: dict, times, dests, writes, depths=None,
     configuration's).  Returns one flat dict per experiment, keyed as
     ``bench.compare.flatten`` keys a ``SimResult``."""
     topo = cfg["topology"]
-    if topo["kind"] != "mesh" or topo.get("routing", "xy") != "xy":
-        raise ValueError(f"the reference models XY meshes, not {topo}")
+    if (topo["kind"] != "mesh" or topo.get("routing", "xy") != "xy"
+            or topo.get("n_vcs", 1) != 1 or topo.get("express")):
+        raise ValueError(f"the reference models single-VC XY meshes, not "
+                         f"{topo}")
+    if any(c.get("n_streams", 1) != 1 or c.get("service_jitter", 0)
+           for c in cfg["classes"]):
+        raise ValueError("the reference models one AXI ID stream and a "
+                         "fixed service latency per class")
     nx, ny = topo["nx"], topo["ny"]
     R = nx * ny
     plan = Plan(cfg)

@@ -37,7 +37,7 @@ def main(argv=None) -> int:
     from bench import harness
     try:
         cell = harness.resolve(args.workload, ROOT)
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, ValueError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
 

@@ -16,7 +16,8 @@ number of cycles the window saw is the most common event count among
 the device's operation names, and those names are the loop's ops (ops
 outside the loop run once per call).  The longest stretch with no loop
 op is the boundary: the time per experiment that the device loop does
-not run.  :func:`reduce` recovers all of it from the trace alone.
+not run.  :func:`reduce` recovers all of it from the trace alone;
+:func:`reduce_split` adds the split by the program's scopes and spans.
 """
 from __future__ import annotations
 
@@ -218,34 +219,43 @@ def boundary_parts(bound, starts, ends) -> list[tuple[str, float]]:
              f"dispatch)", b1 - start)]
 
 
-def load(path: Path) -> list:
-    """The planes of an ``.xplane.pb`` file as plain lists."""
-    import jax
-    pd = jax.profiler.ProfileData.from_file(str(path))
-    return [{"name": p.name,
-             "lines": [{"name": ln.name,
-                        "events": [(e.name, e.start_ns,
-                                    e.start_ns + e.duration_ns)
-                                   for e in ln.events]}
-                       for ln in p.lines]}
-            for p in pd.planes]
+def reduce_split(planes, points: int) -> dict | None:
+    """:func:`reduce`'s numbers with the program's own split merged in
+    (``bench/phases.py``): busy time by phase and by ``noc.*`` scope
+    (``phase_s_per_iteration``, ``scope_s_per_iteration``,
+    ``unscoped_s_per_iteration``) and the boundary by span
+    (``host_prepare_s_per_experiment``,
+    ``host_results_s_per_experiment``, ``span_s_per_experiment``).
+    Where both give a key, :func:`reduce`'s value stays.  ``planes`` as
+    ``bench.phases.load`` gives them; ``None`` where :func:`reduce`
+    finds no device loop."""
+    from bench import phases
+    out = reduce(phases.plain(planes), points)
+    if out is None:
+        return None
+    split = {**(phases.reduce_host(planes) or {}),
+             **(phases.reduce_phases(planes, points) or {})}
+    return {**split, **out}
 
 
-def traced_call(system, trace_dir: Path, expected_s: float) -> dict | None:
-    """Trace the boundary between two more experiment calls (the first
-    expected to take ``expected_s``) and reduce it.  The trace files are
-    deleted once read."""
+def capture(system, trace_dir: Path, expected_s: float) -> list[float]:
+    """Run two more experiment calls (the first expected to take
+    ``expected_s``) with the profiler on across the boundary between
+    them, its files under ``trace_dir``; returns the calls' seconds."""
     import jax
     shutil.rmtree(trace_dir, ignore_errors=True)
+    secs = []
 
     def work():
         for experiment in (10 ** 6, 10 ** 6 + 1):
+            a = time.perf_counter()
             with jax.profiler.TraceAnnotation(START):
                 pass
             with jax.profiler.TraceAnnotation(SPAN):
                 system.call(experiment)
             with jax.profiler.TraceAnnotation(END):
                 pass
+            secs.append(time.perf_counter() - a)
 
     th = threading.Thread(target=work)
     th.start()
@@ -256,13 +266,33 @@ def traced_call(system, trace_dir: Path, expected_s: float) -> dict | None:
     finally:
         jax.profiler.stop_trace()
     th.join()
-    t = time.perf_counter()
+    return secs
+
+
+def traced_call(system, trace_dir: Path, expected_s: float) -> dict | None:
+    """Trace the boundary between two more experiment calls
+    (:func:`capture`) and reduce it with :func:`reduce_split`, adding
+    the seconds spent lowering the programs' HLO (``hlo_s``, once per
+    run) and reducing (``reduce_s``).  The trace files are deleted once
+    read."""
+    from bench import phases
+    capture(system, trace_dir, expected_s)
+    t, lowering = time.perf_counter(), []
+
+    def hlo():
+        a = time.perf_counter()
+        try:
+            return phases.compiled_hlo()
+        finally:
+            lowering.append(time.perf_counter() - a)
+
     files = sorted(trace_dir.glob("**/*.xplane.pb"))
     try:
-        planes = load(files[0]) if files else []
+        planes = phases.load(files[0], hlo=hlo)[0] if files else []
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    out = reduce(planes, len(system.specs))
+    out = reduce_split(planes, len(system.specs))
     if out is not None:
-        out["reduce_s"] = time.perf_counter() - t
+        out["hlo_s"] = sum(lowering)
+        out["reduce_s"] = time.perf_counter() - t - out["hlo_s"]
     return out

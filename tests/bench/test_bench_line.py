@@ -34,10 +34,12 @@ def test_last_line_shape(tmp_path):
 
 def test_traced_line_on_cpu_has_no_device_metrics(tmp_path):
     """The CPU trace holds no device plane: the readers find nothing and
-    the line leaves their metrics out, never writing a 0."""
+    the line leaves their metrics out, never writing a 0.  Only the
+    set-up build time, read from the program's span table, is there."""
     line = run_tiny(tiny_root(tmp_path), seconds=0.3, trace=True)
     assert line["correct"] is True
-    assert line["metrics"] == {}
+    assert set(line["metrics"]) == {"setup_build_s"}
+    assert line["metrics"]["setup_build_s"]["value"] > 0
     assert "breakdown" not in line and "busy_s" not in line["device"]
     assert not (tmp_path / ".bench_trace").exists()
 

@@ -95,11 +95,64 @@ def test_cell_from_new_files_only(tmp_path):
         "name": "calls_in_window", "unit": "calls", "better": "higher",
         "bound": 0.25, "source": "host_clock", "workloads": ["tiny"]})
     (root / "BENCHMARK.json").write_text(json.dumps(man))
-    line = run_tiny(root, seconds=0.5)
+    # a 4x4 call takes 0.1-0.4 s on the CPU, loaded or not: the window
+    # holds the two calls experiment_s_p90 needs with room to spare
+    line = run_tiny(root, seconds=3.0)
     assert line["correct"] is True
     assert line["metrics"]["calls_in_window"]["value"] == line["attempted"]
     assert set(line["metrics"]) == {"router_cycles_per_s", "setup_s",
                                     "experiment_s_p90", "calls_in_window"}
+    for p, data in before.items():            # the repo's files untouched
+        assert p.read_bytes() == data
+
+
+def test_metric_of_a_new_scope_from_new_files_only(tmp_path, monkeypatch):
+    """A metric reader added as a new file reads a scope the program
+    nests in a phase, and a new host span, from the traced run's
+    reduction, with no file that was there edited.  The CPU's trace has
+    no device plane, so the traced run is handed a synthetic one."""
+    from bench import phases
+    from test_bench_phases import nested
+    root = tiny_root(tmp_path)
+    before = {p: p.read_bytes() for p in (REPO / "bench").rglob("*")
+              if p.is_file() and "__pycache__" not in p.parts}
+    (root / "bench/metrics/vc_arb_us_per_cycle.py").write_text(
+        "def read(run):\n"
+        "    tr = run['trace']\n"
+        "    s = (tr or {}).get('scope_s_per_iteration', {}).get("
+        "'noc.vc_arb')\n"
+        "    return None if s is None else 1e6 * s / tr['points']\n")
+    (root / "bench/metrics/plan_ms_per_experiment.py").write_text(
+        "def read(run):\n"
+        "    tr = run['trace'] or {}\n"
+        "    s = tr.get('span_s_per_experiment', {}).get('noc.plan')\n"
+        "    return None if s is None else 1e3 * s\n")
+    man = json.loads((root / "BENCHMARK.json").read_text())
+    man["per_layer"] += [
+        {"name": "vc_arb_us_per_cycle", "unit": "us", "better": "lower",
+         "source": "device_trace", "layer": "engine scan step",
+         "moves": "router_cycles_per_s", "workloads": ["tiny"]},
+        {"name": "plan_ms_per_experiment", "unit": "ms", "better": "lower",
+         "source": "device_trace", "layer": "API",
+         "moves": "experiment_s_p90", "workloads": ["tiny"]}]
+    (root / "BENCHMARK.json").write_text(json.dumps(man))
+    monkeypatch.setattr(phases, "load",
+                        lambda path, hlo=None, sample=0: (nested(), []))
+    line = run_tiny(root, seconds=0.3, trace=True)
+    assert line["correct"] is True
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    assert got["vc_arb_us_per_cycle"] == pytest.approx(0.5)
+    assert got["plan_ms_per_experiment"] == pytest.approx(0.05)
+    split = {f"{k}_us_per_cycle": v for k, v in [
+        ("ni_issue", 3.0), ("fabric", 2.5), ("ni_rings", 0.0),
+        ("stats", 0.0), ("unscoped", 1.0)]}
+    assert {k: got[k] for k in split} == pytest.approx(split)
+    assert got["device_us_per_cycle"] == pytest.approx(6.5)
+    assert got["host_prepare_ms_per_experiment"] == pytest.approx(1.25)
+    assert got["host_results_ms_per_experiment"] == pytest.approx(0.4)
+    assert got["host_ms_per_experiment"] == pytest.approx(2.0)
+    assert got["setup_build_s"] > 0
+    assert line["device"]["busy_s"] > 0 and "breakdown" in line
     for p, data in before.items():            # the repo's files untouched
         assert p.read_bytes() == data
 

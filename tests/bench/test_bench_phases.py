@@ -59,6 +59,28 @@ def synthetic(a=30, b=20, devices=1, body=BODY, spans=True):
     return planes
 
 
+# the body with a scope nested in a phase: a 0.5 us op under
+# noc.fabric/noc.vc_arb
+NESTED = BODY + [("fusion.v", 500, "noc.fabric/noc.vc_arb")]
+
+
+def nested(flat=False, plan=True):
+    """The trace of :func:`synthetic` on ``NESTED`` (each op only its
+    outermost phase if ``flat``), with a new host span ``noc.plan`` of
+    call 2 from 0.6 to 0.65 ms into the boundary if ``plan``."""
+    planes = synthetic(body=NESTED)
+    if flat:
+        for p in planes[:-1]:
+            p["lines"][0]["events"] = [
+                (n, s, e, phases.phase_of(sc))
+                for n, s, e, sc in p["lines"][0]["events"]]
+    host = planes[-1]["lines"][0]["events"]
+    end_a = next(s for n, s, _, _ in host if n == tracing.END) - 500_000
+    if plan:
+        host.append(span("noc.plan", end_a + 600_000, end_a + 650_000, 2))
+    return planes
+
+
 def test_phases_and_rest_add_up_to_busy():
     out = phases.reduce_phases(synthetic(), points=1)
     ph = out["phase_s_per_iteration"]
@@ -110,7 +132,8 @@ def test_per_point_and_over_devices():
 ])
 def test_outermost_phase_wins(op_name, phase):
     assert phases.phase_of(op_name) == phase
-    assert phases.phase_of_stats({"tf_op": op_name}) == phase
+    assert phases.phase_of(phases.scopes_of_stats({"tf_op": op_name})) \
+        == phase
 
 
 def test_a_scope_under_the_halo_counts_toward_the_fabric():
@@ -151,7 +174,7 @@ HLO = """HloModule jit_run
 
 
 def test_hlo_fallback_gives_a_fusion_its_roots_phase():
-    got = phases.hlo_phases(HLO)
+    got = phases.hlo_scopes(HLO)
     assert got["fusion.9"] == "noc.ni_rings"
     assert got["scatter.3"] == "noc.ni_rings"
     assert got["add.2"] == "noc.stats"
@@ -224,3 +247,56 @@ def test_nothing_to_read_without_scopes_or_spans():
                                                   "events": []}]}]
     assert phases.reduce_phases(host_only, points=1) is None
     assert phases.reduce_host(host_only) is None
+
+
+def test_a_nested_scope_and_a_new_span_are_keys():
+    """A scope nested in a phase and a host span the reduction does not
+    name each get an entry of their own, and every number that was
+    there before reads as on the trace without them."""
+    out = phases.reduce_phases(nested(), points=1)
+    assert out["scope_s_per_iteration"] == pytest.approx({
+        "noc.ni_issue": 3e-6, "noc.fabric": 2.5e-6, "noc.vc_arb": 0.5e-6})
+    flat = phases.reduce_phases(nested(flat=True), points=1)
+    assert out["phase_s_per_iteration"] == flat["phase_s_per_iteration"]
+    assert out["phase_s_per_iteration"]["noc.fabric"] == pytest.approx(
+        2.5e-6)
+    assert "noc.vc_arb" not in flat["scope_s_per_iteration"]
+    old = tracing.reduce(phases.plain(nested(flat=True, plan=False)),
+                         points=1)
+    assert tracing.reduce(phases.plain(nested()), points=1) == old
+    host = phases.reduce_host(nested())
+    before = phases.reduce_host(nested(plan=False))
+    assert host["span_s_per_experiment"]["noc.plan"] == pytest.approx(
+        0.05e-3)
+    assert "noc.plan" not in before["span_s_per_experiment"]
+    for k in ("host_s_per_experiment", "host_prepare_s_per_experiment",
+              "host_results_s_per_experiment"):
+        assert host[k] == before[k], k
+    # the traced run's reduction: reduce's own numbers, the splits beside
+    merged = tracing.reduce_split(nested(), points=1)
+    assert {k: merged[k] for k in old} == old
+    assert merged["scope_s_per_iteration"] == out["scope_s_per_iteration"]
+    assert merged["host_prepare_s_per_experiment"] == pytest.approx(1.25e-3)
+
+
+def test_span_totals_count_a_nested_span_in_its_parents():
+    planes = nested()
+    got = phases.reduce_host(planes)["span_s_per_experiment"]
+    # call 1's noc.simulate and noc.wait began before the window
+    assert got == pytest.approx({
+        "noc.results": 0.4e-3, "noc.simulate": 1.4e-3, "noc.plan": 0.05e-3,
+        "noc.verify": 0.05e-3, "noc.schedules": 0.5e-3,
+        "noc.dispatch": 0.7e-3, "noc.wait": 0.1e-3})
+
+
+@pytest.mark.parametrize("op_name, path", [
+    ("jit(run)/while/body/noc.fabric/fabric_update/noc.vc_arb/select",
+     "noc.fabric/noc.vc_arb"),
+    ("jit(run)/while/body/noc.stats/farm.halo/noc.fabric/x",
+     "noc.stats/noc.fabric"),
+    ("jit(run)/while/body/closed_call/add", None),
+])
+def test_scope_path_keeps_every_program_scope(op_name, path):
+    assert phases.scopes_of(op_name) == path
+    assert phases.scopes_of_stats({"long_name": op_name}) == path
+    assert phases.phase_of(path) == phases.phase_of(op_name)
