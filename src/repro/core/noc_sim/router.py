@@ -211,9 +211,8 @@ class Front(NamedTuple):
 
 def serialize_drain(ready: jax.Array, n_vcs: int) -> jax.Array:
     """At most one drained VC per physical link: highest ready VC index
-    wins (escape-VC priority).  Identity when ``n_vcs == 1``."""
-    if n_vcs == 1:
-        return ready
+    wins (escape-VC priority).  The front calls it only where ``n_vcs >
+    1``, under the ``noc.vc_drain`` scope."""
     R, P = ready.shape
     e = ready[:, :P - 1].reshape(R, (P - 1) // n_vcs, n_vcs)
     rank = jnp.where(e, jnp.arange(n_vcs)[None, None, :], -1)
@@ -250,7 +249,10 @@ def fabric_front(state: NetState, inject_valid: jax.Array,
                           (tables.nbr >= 0) & (ds_count < depth))
     if link_mask is not None:
         can_drain &= ~link_mask
-    drain = serialize_drain(state.oreg_v & can_drain, n_vcs)
+    drain = state.oreg_v & can_drain
+    if n_vcs > 1:
+        with jax.named_scope("noc.vc_drain"):
+            drain = serialize_drain(drain, n_vcs)
 
     # pushes into neighbor input FIFOs, as ONE static gather through
     # the inverse link map (each input port has at most one feeder,
